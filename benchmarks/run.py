@@ -172,10 +172,10 @@ def bench_line_detect():
 def bench_collectives():
     script = r"""
 import jax, jax.numpy as jnp, time
-from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.cpm import collectives
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 x = jnp.ones((8, 4096))
 for name, fn in [
     ("ring", lambda v: collectives.ring_allreduce(v, "data")),
@@ -1308,6 +1308,8 @@ SCENARIOS = {
 
 
 def main(argv=None) -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = list(argv if argv is not None else sys.argv[1:])
     json_flag, json_path = False, None
     if "--json" in args:                       # --json [PATH]: machine-

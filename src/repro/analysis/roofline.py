@@ -2,9 +2,11 @@
 
 Three terms per (arch × shape × mesh), all in seconds-per-step-per-chip:
 
-    compute    = HLO_FLOPs / (chips × 197e12)          [bf16 peak]
-    memory     = HLO_bytes / (chips × 819e9)           [HBM]
-    collective = collective_bytes / 50e9               [per-chip ICI bytes]
+    compute    = HLO_FLOPs / (chips × peak_flops)      [bf16 peak]
+    memory     = HLO_bytes / (chips × hbm_bw)          [HBM]
+    collective = collective_bytes / ici_bw             [per-chip ICI bytes]
+
+with the peaks of the device at hand (:func:`hw`, keyed by ``device_kind``).
 
 ``cost_analysis()`` visits while-loop bodies once, so HLO_FLOPs/bytes come
 from the unrolled 1-unit / 2-unit probe extrapolation (dryrun.py), and
@@ -25,11 +27,37 @@ import dataclasses
 import re
 from collections import defaultdict
 
-HW = {
-    "peak_flops": 197e12,      # bf16 per chip
-    "hbm_bw": 819e9,           # bytes/s per chip
-    "ici_bw": 50e9,            # bytes/s per link
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: TPU v5e ("TPU v5 lite" to the compiler): Google Cloud documentation,
+#: "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip
+#: interconnect (ICI) per chip.
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,      # bf16 FLOP/s per chip
+        "hbm_bw": 819e9,           # HBM bytes/s per chip
+        "ici_bw": 1600e9 / 8,      # ICI bytes/s per chip
+    },
 }
+
+#: Priors for a host that is no TPU (CPU tests, dry runs): the v5e figures,
+#: so a run off the chip prices work as the chip would.
+CPU_PRIORS = PEAKS["TPU v5 lite"]
+
+
+def hw() -> dict:
+    """Peaks of the first local device.  A TPU whose ``device_kind`` has no
+    entry in :data:`PEAKS` raises — an unknown chip is an error, not a
+    default; other platforms get :data:`CPU_PRIORS`."""
+    import jax
+    device = jax.local_devices()[0]
+    if device.platform != "tpu":
+        return CPU_PRIORS
+    try:
+        return PEAKS[device.device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for TPU device kind "
+                       f"{device.device_kind!r}; add them to roofline.PEAKS "
+                       f"with their source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -165,9 +193,10 @@ def parse_hlo(hlo_text: str, total_devices: int) -> CollectiveStats:
 
 def roofline_terms(flops_per_chip: float, bytes_per_chip: float,
                    coll_bytes_per_chip: float) -> dict:
-    t_c = flops_per_chip / HW["peak_flops"]
-    t_m = bytes_per_chip / HW["hbm_bw"]
-    t_x = coll_bytes_per_chip / HW["ici_bw"]
+    peaks = hw()
+    t_c = flops_per_chip / peaks["peak_flops"]
+    t_m = bytes_per_chip / peaks["hbm_bw"]
+    t_x = coll_bytes_per_chip / peaks["ici_bw"]
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_x),
               key=lambda kv: kv[1])[0]
     return {"compute_s": t_c, "memory_s": t_m, "collective_s": t_x,

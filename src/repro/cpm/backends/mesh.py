@@ -13,8 +13,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 
 from .. import collectives
 from . import _TableBacked
@@ -34,7 +34,8 @@ class MeshBackend(_TableBacked):
                                 else mesh.axis_names[0])
             else:
                 devs = jax.devices()
-                mesh = jax.make_mesh((len(devs),), ("cpm",))
+                mesh = jax.make_mesh((len(devs),), ("cpm",),
+                                     axis_types=(AxisType.Auto,))
                 axis = "cpm"
         self.mesh = mesh
         self.axis = axis or mesh.axis_names[0]
@@ -85,14 +86,14 @@ class MeshBackend(_TableBacked):
     def super_sum(self, x, section=None):
         """§8 on chips: local partial per device, log-depth butterfly
         combine over the mesh axis (``collectives.tree_allreduce``).
-        ``check_rep=False``: the ppermute butterfly leaves every device
+        ``check_vma=False``: the ppermute butterfly leaves every device
         holding the full combine, but shard_map's static replication
         checker cannot prove that."""
         xp = self._pad(x, 0)
         f = shard_map(
             lambda xl: collectives.distributed_super_sum(xl, self.axis),
             mesh=self.mesh, in_specs=self._spec(x.ndim), out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         return f(xp)
 
     def super_limit(self, x, mode="max", section=None):
@@ -102,5 +103,5 @@ class MeshBackend(_TableBacked):
             lambda xl: collectives.distributed_super_limit(
                 xl, self.axis, mode=mode),
             mesh=self.mesh, in_specs=self._spec(x.ndim), out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         return f(xp)

@@ -46,10 +46,12 @@ class PallasBackend(_TableBacked):
         ``section=`` from the caller always bypasses tuning (this is
         only reached when it was None)."""
         n = x.shape[-1]
-        default = min(default, n)
+        default = K.lane_section(default, n)
         if n < 2048:                    # tuning overhead beats any return
             return default
-        cands = sorted({min(c, n) for c in
+        # shape rule before timing: each candidate as the kernel would
+        # really run it (whole lane tiles), so every one compiles
+        cands = sorted({K.lane_section(c, n) for c in
                         (optimal_section(n), 256, 1024, 4096, n)})
         key = (f"section:{op}|{'x'.join(map(str, x.shape))}"
                f"|{jnp.dtype(x.dtype).name}"

@@ -39,9 +39,11 @@ class SlotAllocator:
     optional file of ``n_pages`` *sub-pages* with per-session page lists.
 
     ``backend``/``interpret`` route the metadata queries like any other
-    ``CPMArray`` (reference by default; pallas for kernel-resident
-    metadata).  All methods are host-synchronous by design — allocation is
-    admission control, a host decision — but each decision costs O(1)
+    ``CPMArray`` (``"auto"`` by default, the per-array rule of
+    ``backends.auto_backend_name``: pallas for metadata resident on a TPU
+    once the file is long enough, reference otherwise).
+    All methods are host-synchronous by design — allocation is admission
+    control, a host decision — but each decision costs O(1)
     concurrent CPM steps, not a host-side scan over slots.
 
     With ``n_pages > 0`` the allocator also owns the sub-page metadata
@@ -52,7 +54,7 @@ class SlotAllocator:
     pages together, so a retire or cancel can never leak a sub-page.
     """
 
-    def __init__(self, n_slots: int, backend: str = "reference",
+    def __init__(self, n_slots: int, backend: str = "auto",
                  interpret: bool | None = None, n_pages: int = 0):
         if n_slots <= 0:
             raise ValueError(f"n_slots must be positive, got {n_slots}")

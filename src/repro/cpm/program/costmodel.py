@@ -27,10 +27,11 @@ either
     fixed probe stream timed fused vs eager at two sizes, solved for the
     launch intercepts and per-byte slopes, spilled to the tuning-cache
     JSON (``repro.cpm.tuning``) for reuse across runs; or
-  * **roofline priors** — ``analysis.roofline.HW`` HBM bandwidth plus a
-    nominal launch cost, used where measurement is impossible or disabled
-    (``REPRO_CPM_CALIBRATE=0``).  The priors make fusion profitable for
-    any multi-op run — the correct TPU-side default.
+  * **roofline priors** — the device's HBM bandwidth
+    (``analysis.roofline.hw``) plus a nominal launch cost, used where
+    measurement is impossible or disabled (``REPRO_CPM_CALIBRATE=0``).
+    The priors make fusion profitable for any multi-op run — the correct
+    TPU-side default.
 
 ``schedule(prog, device=...)`` consults :func:`decide` per fusable run
 and records the verdict in the emitted :class:`FusionGroup`.
@@ -45,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.analysis.roofline import HW
+from repro.analysis.roofline import hw
 
 from .. import tuning
 
@@ -81,9 +82,10 @@ class CostParams:
 
 
 def roofline_params() -> CostParams:
-    """Priors from the §9 roofline HW table: byte slopes at HBM bandwidth
-    (identical for both paths — launches decide), nominal launch cost."""
-    byte_s = 1.0 / HW["hbm_bw"]
+    """Priors from the roofline peaks table: byte slopes at the device's
+    HBM bandwidth (identical for both paths — launches decide), nominal
+    launch cost."""
+    byte_s = 1.0 / hw()["hbm_bw"]
     return CostParams(NOMINAL_LAUNCH_S, byte_s, NOMINAL_LAUNCH_S, byte_s,
                       source="roofline")
 
@@ -145,7 +147,9 @@ def calibrate(interpret: bool) -> CostParams:
 
 def params_for(interpret: bool) -> CostParams:
     """The coefficients for one backend key: tuning-cache hit, else a
-    fresh calibration (spilled), else the roofline priors."""
+    fresh calibration (spilled), else — calibration off, or under a
+    trace — the roofline priors.  A calibration that fails to compile or
+    run raises: it is a fault of the kernels, not a reason to guess."""
     key = f"calib:{tuning.backend_key(interpret)}"
     cached = tuning.lookup(key)
     if isinstance(cached, dict):
@@ -158,10 +162,7 @@ def params_for(interpret: bool) -> CostParams:
         # price with the roofline priors (uncached, so a later eager
         # schedule still gets to calibrate)
         return roofline_params()
-    try:
-        params = calibrate(interpret)
-    except Exception:
-        return roofline_params()
+    params = calibrate(interpret)
     tuning.store(key, params.as_dict())
     return params
 
@@ -229,7 +230,7 @@ def _measured_fuse(instructions, lead, n: int, dtype,
                    interpret: bool) -> dict | None:
     """Time the run fused vs eager on a synthesized device of the real
     geometry; returns the verdict dict or None (cache miss while tuning
-    is off or a trace is active, or measurement failure)."""
+    is off or a trace is active).  Measurement errors propagate."""
     from ..array import CPMArray
     from . import executors
     from .ir import CPMProgram
@@ -263,14 +264,9 @@ def _measured_fuse(instructions, lead, n: int, dtype,
             return cur.data, [o for o in outs if o is not None]
         return jax.jit(go)
 
-    try:
-        f_fused, f_eager = runner(fused_plan), runner(eager_plan)
-        t_fused = tuning.time_call(lambda: f_fused(data),
-                                   reps=_MEASURE_REPS)
-        t_eager = tuning.time_call(lambda: f_eager(data),
-                                   reps=_MEASURE_REPS)
-    except Exception:
-        return None
+    f_fused, f_eager = runner(fused_plan), runner(eager_plan)
+    t_fused = tuning.time_call(lambda: f_fused(data), reps=_MEASURE_REPS)
+    t_eager = tuning.time_call(lambda: f_eager(data), reps=_MEASURE_REPS)
     verdict = {"fuse": bool(t_fused <= t_eager),
                "fused_us": t_fused * 1e6, "eager_us": t_eager * 1e6}
     tuning.store(key, verdict)

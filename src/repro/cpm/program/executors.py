@@ -184,6 +184,9 @@ _TUNE_MIN_ELEMS = 1 << 15
 
 
 def _blockr_candidates(r: int) -> list[int]:
+    """Row blockings that compile for the chip: one row (the kernel's
+    (R, 1, N) layout), multiples of 8 rows, or all rows
+    (``tests/test_tpu_compile.py`` compiles each for a v5e)."""
     return sorted({br for br in (1, 8, 32, r) if 1 <= br <= r})
 
 

@@ -117,8 +117,10 @@ def measurable() -> bool:
     "timed" jit dispatch is *staged* into the enclosing jaxpr instead of
     run (omnistaging), so the wall clock would measure tracing and the
     staged calls would pollute the traced program — callers must skip
-    measurement and fall back to cached decisions or static defaults."""
-    return jax.core.trace_state_clean()
+    measurement and fall back to cached decisions or static defaults.
+    The one "is a trace active" check of the repo (``obs.cycles`` uses it
+    too)."""
+    return jax.core.trace_ctx.is_top_level()
 
 
 def synth(shape, dtype):
@@ -152,10 +154,11 @@ def pick(key: str, candidates: list, run: Callable[[Any], Any],
          default, reps: int = 3):
     """Cached argmin-time choice among ``candidates``.
 
-    ``run(c)`` executes one candidate on synthesized inputs; failures (a
-    candidate invalid for the shape) disqualify that candidate.  With
-    tuning disabled, an active trace (see :func:`measurable`), or every
-    candidate failing, returns ``default`` without caching, so the
+    ``run(c)`` executes one candidate on synthesized inputs.  Callers
+    offer only candidates the shape can take (a shape rule, applied before
+    timing), so a candidate that fails to compile or run is a fault and
+    its error propagates.  With tuning disabled or an active trace (see
+    :func:`measurable`), returns ``default`` without caching, so the
     decision can be made later under better conditions.
     """
     cached = lookup(key)
@@ -163,15 +166,7 @@ def pick(key: str, candidates: list, run: Callable[[Any], Any],
         return cached
     if not tuning_enabled() or not measurable() or not candidates:
         return default
-    best, best_t = default, float("inf")
-    for c in candidates:
-        try:
-            t = time_call(lambda: run(c), reps=reps)
-        except Exception:
-            continue
-        if t < best_t:
-            best, best_t = c, t
-    if best_t == float("inf"):
-        return default
+    times = {c: time_call(lambda: run(c), reps=reps) for c in candidates}
+    best = min(candidates, key=times.__getitem__)
     store(key, best)
     return best

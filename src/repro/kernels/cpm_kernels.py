@@ -32,8 +32,9 @@ Kernels:
                           wrapping, matching the canonical `repro.cpm`
                           semantics).
   * ``compact``         — §4.2 stable pack of flagged items: a log-depth
-                          Hillis-Steele cumsum over the keep flags followed
-                          by a log-depth per-lane lower-bound gather —
+                          Hillis-Steele cumsum over the keep flags, then
+                          each kept item shifts left by its count of
+                          dropped predecessors, one roll per bit —
                           ~2·log2(N) concurrent steps, bit-identical to the
                           reference argsort pack.
   * ``gather_rows`` / ``scatter_rows`` — paged-row movement for the bank
@@ -59,13 +60,83 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def pallas_native() -> bool:
+    """The one platform rule behind every kernel default: Pallas kernels
+    run compiled on a TPU; anywhere else the defaults pick the jnp
+    reference, and a Pallas kernel asked for explicitly runs under the
+    interpreter."""
+    return jax.default_backend() == "tpu"
+
+
 def resolve_interpret(interpret: bool | None) -> bool:
-    """The one interpret auto rule (shared with ``CPMArray`` and
-    ``PallasBackend``): run kernel bodies compiled on TPU, under the Pallas
-    interpreter everywhere else.  ``None`` means auto."""
+    """Interpret flag of a Pallas call (shared with ``CPMArray``,
+    ``PallasBackend`` and ``flash_attention``).  ``None`` means auto."""
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return not pallas_native()
     return bool(interpret)
+
+
+def resolve_backend(backend: str | None) -> str:
+    """CPM backend of a serving-path component (``Engine`` commit,
+    ``SessionPool``/``Gateway`` token banks).  ``None`` means auto:
+    ``"pallas"`` on a TPU, ``"reference"`` elsewhere."""
+    if backend is None:
+        return "pallas" if pallas_native() else "reference"
+    return backend
+
+
+# ---------------------------------------------------------------------------
+# TPU block layout
+# ---------------------------------------------------------------------------
+#
+# Mosaic takes a block only when its last two dims are multiples of
+# (8, 128) or equal to the array's.  Row-batched kernels therefore see
+# their (R, N) rows as an (R, 1, N) array: one (1, N) row per grid step —
+# last two dims equal to the array's — with the row axis squeezed out of
+# the kernel's view, so bodies still work on (1, N) rows.
+
+LANES = 128
+
+
+def _as_rows3(x: jax.Array) -> jax.Array:
+    """(R, N) -> (R, 1, N), the layout of every row-batched kernel."""
+    return x.reshape(x.shape[0], 1, x.shape[-1])
+
+
+def _row_spec(width: int, index_map) -> pl.BlockSpec:
+    """One (1, ``width``) row of an (R, 1, N) array per grid step."""
+    return pl.BlockSpec((pl.squeezed, 1, width), index_map)
+
+
+def _each_row(width: int) -> pl.BlockSpec:
+    """Grid step ``i`` takes row ``i`` whole."""
+    return _row_spec(width, lambda i: (i, 0, 0))
+
+
+def _roll(x, shift: int):
+    """``jnp.roll`` along lanes by a static shift.  Mosaic refuses the
+    empty slice of a zero roll and rolls of booleans, so a zero shift is
+    the identity here and callers roll int32 masks."""
+    shift %= x.shape[-1]
+    return jnp.roll(x, shift, axis=-1) if shift else x
+
+
+def _lane_pick(v, j):
+    """Lane ``j`` (traced) of every row of a small (rows, M) block, as a
+    (rows, 1) column — a masked sum, since Mosaic has no dynamic lane
+    slice.  Exact: every other term is zero."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    return jnp.sum(jnp.where(lane == j, v, jnp.zeros((), v.dtype)), axis=1,
+                   keepdims=True)
+
+
+def lane_section(section: int, n: int) -> int:
+    """The section width a sectioned kernel really uses: ``section`` rounded
+    up to whole 128-lane tiles (Mosaic's block rule), or the whole row when
+    that is no shorter.  Shared with the autotuner's candidate filter."""
+    if section >= n:
+        return n
+    return min(n, -(-section // LANES) * LANES)
 
 
 # ---------------------------------------------------------------------------
@@ -109,12 +180,9 @@ def _shift_vals(x, idx, start, end, shift: int, n: int, fill=None):
     """§4.1 range move of a resident block — the one value-level body shared
     by the standalone kernel and the fused instruction stream."""
     src_mask = (idx >= start) & (idx <= end)
-    moved = jnp.roll(x, shift, axis=-1)
-    dst_mask = jnp.roll(src_mask, shift, axis=-1)
-    if shift > 0:
-        dst_mask = dst_mask & (idx >= shift)
-    elif shift < 0:
-        dst_mask = dst_mask & (idx < n + shift)
+    moved = _roll(x, shift)
+    j = idx - shift                     # the lane each lane's content left
+    dst_mask = (j >= start) & (j <= end) & (j >= 0) & (j < n)
     out = jnp.where(dst_mask, moved, x)
     if fill is not None:
         out = jnp.where(src_mask & ~dst_mask, fill, out)
@@ -146,13 +214,13 @@ def shift_range(x: jax.Array, start, end, shift: int = 1, fill=None, *,
         functools.partial(_shift_range_kernel, n=n, shift=shift,
                           has_fill=fill is not None),
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (i, 0)),
+        in_specs=[_each_row(n),
                   pl.BlockSpec((1, 2), lambda i: (0, 0)),
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, n), x.dtype),
+        out_specs=_each_row(n),
+        out_shape=jax.ShapeDtypeStruct((r, 1, n), x.dtype),
         interpret=resolve_interpret(interpret),
-    )(x, params, fill_arr)
+    )(_as_rows3(x), params, fill_arr).reshape(r, n)
 
 
 # ---------------------------------------------------------------------------
@@ -165,7 +233,7 @@ def _oddeven_kernel(x_ref, o_ref, *, n: int, steps: int):
     def body(i, x):
         is_left = (idx % 2) == (i % 2)
         partner = jnp.clip(jnp.where(is_left, idx + 1, idx - 1), 0, n - 1)
-        px = jnp.take_along_axis(x, partner, axis=1)
+        px = jnp.where(is_left, _roll(x, -1), _roll(x, 1))   # neighbor read
         out = jnp.where(is_left, jnp.minimum(x, px), jnp.maximum(x, px))
         solo = (partner == idx) | (is_left & (idx == n - 1))
         return jnp.where(solo, x, out)
@@ -182,11 +250,11 @@ def oddeven_sort(x: jax.Array, steps: int | None = None, *,
     return pl.pallas_call(
         functools.partial(_oddeven_kernel, n=n, steps=steps),
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, n), x.dtype),
+        in_specs=[_each_row(n)],
+        out_specs=_each_row(n),
+        out_shape=jax.ShapeDtypeStruct((r, 1, n), x.dtype),
         interpret=resolve_interpret(interpret),
-    )(x)
+    )(_as_rows3(x)).reshape(r, n)
 
 
 # ---------------------------------------------------------------------------
@@ -237,17 +305,18 @@ def section_sum(x: jax.Array, section: int = 1024, *,
     matching ``jnp.sum`` semantics); floats accumulate in float32.
     """
     acc_dtype = _acc_dtype(x.dtype)
+    section = lane_section(section, x.shape[-1])
     xs, nsec, unflatten = _pad_rows(x, section)
     r = xs.shape[0]
     out = pl.pallas_call(
         _section_sum_kernel,
         grid=(r, nsec),
-        in_specs=[pl.BlockSpec((1, section), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, 1), acc_dtype),
+        in_specs=[_row_spec(section, lambda i, j: (i, 0, j))],
+        out_specs=_row_spec(1, lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((r, 1, 1), acc_dtype),
         scratch_shapes=[pltpu.VMEM((1, 1), acc_dtype)],
         interpret=resolve_interpret(interpret),
-    )(xs)
+    )(_as_rows3(xs))
     return unflatten(out).astype(jnp.promote_types(x.dtype, acc_dtype))
 
 
@@ -284,13 +353,13 @@ def compare(x: jax.Array, datum, op: str = "eq", *,
     out = pl.pallas_call(
         functools.partial(_compare_kernel, op=op),
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (i, 0)),
+        in_specs=[_each_row(n),
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, n), jnp.int8),
+        out_specs=_each_row(n),
+        out_shape=jax.ShapeDtypeStruct((r, 1, n), jnp.int8),
         interpret=resolve_interpret(interpret),
-    )(x, d)
-    return out.astype(bool)
+    )(_as_rows3(x), d)
+    return out.reshape(r, n).astype(bool)
 
 
 def _histogram_kernel(x_ref, e_ref, o_ref, acc_ref, *, m: int):
@@ -326,18 +395,19 @@ def histogram(x: jax.Array, edges: jax.Array, section: int = 1024, *,
     ct = jnp.promote_types(x.dtype, edges.dtype)
     x, edges = x.astype(ct), edges.astype(ct)
     m = edges.shape[-1] - 1
+    section = lane_section(section, x.shape[-1])
     xs, nsec, _ = _pad_rows(x, section, fill=edges[-1])
     r = xs.shape[0]
     out = pl.pallas_call(
         functools.partial(_histogram_kernel, m=m),
         grid=(r, nsec),
-        in_specs=[pl.BlockSpec((1, section), lambda i, j: (i, j)),
+        in_specs=[_row_spec(section, lambda i, j: (i, 0, j)),
                   pl.BlockSpec((1, m + 1), lambda i, j: (0, 0))],
-        out_specs=pl.BlockSpec((1, m), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, m), jnp.int32),
+        out_specs=_row_spec(m, lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((r, 1, m), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, m), jnp.int32)],
         interpret=resolve_interpret(interpret),
-    )(xs, edges.reshape(1, m + 1))
+    )(_as_rows3(xs), edges.reshape(1, m + 1))
     return out.reshape(*x.shape[:-1], m)
 
 
@@ -377,18 +447,19 @@ def section_limit(x: jax.Array, section: int = 1024, mode: str = "max", *,
 
     acc_dtype = _acc_dtype(x.dtype)
     fill = limit_identity(acc_dtype, mode)
+    section = lane_section(section, x.shape[-1])
     xs, nsec, unflatten = _pad_rows(x, section,
                                     fill=limit_identity(x.dtype, mode))
     r = xs.shape[0]
     out = pl.pallas_call(
         functools.partial(_section_limit_kernel, mode=mode, init=fill),
         grid=(r, nsec),
-        in_specs=[pl.BlockSpec((1, section), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, 1), acc_dtype),
+        in_specs=[_row_spec(section, lambda i, j: (i, 0, j))],
+        out_specs=_row_spec(1, lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((r, 1, 1), acc_dtype),
         scratch_shapes=[pltpu.VMEM((1, 1), acc_dtype)],
         interpret=resolve_interpret(interpret),
-    )(xs)
+    )(_as_rows3(xs))
     return unflatten(out).astype(x.dtype)
 
 
@@ -406,7 +477,7 @@ def _tree_combine_block(x, k: int, combine, identity):
     levels = max(1, (k - 1).bit_length()) if k > 1 else 0
     for j in range(levels):
         stride = 1 << j
-        partner = jnp.roll(x, -stride, axis=-1)
+        partner = _roll(x, -stride)
         partner = jnp.where(idx + stride < k, partner, identity)
         x = combine(x, partner)
     return x
@@ -419,7 +490,8 @@ def _super_kernel(x_ref, o_ref, acc_ref, *, mode: str, nsec: int, identity):
 
     # phase 1: this section's concurrent partial, parked in its scratch lane
     part = red(x_ref[...].astype(acc_ref.dtype), axis=-1, keepdims=True)
-    acc_ref[:, pl.ds(j, 1)] = part
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
+    acc_ref[...] = jnp.where(lane == j, part, acc_ref[...])
 
     # phase 2: §8 log-depth tree over the section partials (not a march)
     @pl.when(j == nsec - 1)
@@ -437,18 +509,19 @@ def _super_reduce(x: jax.Array, section: int, mode: str, *, interpret: bool):
     else:                                    # must not close over tracers
         pad_fill = limit_identity(x.dtype, mode)
         identity = limit_identity(acc_dtype, mode)
+    section = lane_section(section, x.shape[-1])
     xs, nsec, unflatten = _pad_rows(x, section, fill=pad_fill)
     r = xs.shape[0]
     out = pl.pallas_call(
         functools.partial(_super_kernel, mode=mode, nsec=nsec,
                           identity=identity),
         grid=(r, nsec),
-        in_specs=[pl.BlockSpec((1, section), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, 1), acc_dtype),
+        in_specs=[_row_spec(section, lambda i, j: (i, 0, j))],
+        out_specs=_row_spec(1, lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((r, 1, 1), acc_dtype),
         scratch_shapes=[pltpu.VMEM((1, nsec), acc_dtype)],
         interpret=resolve_interpret(interpret),
-    )(xs)
+    )(_as_rows3(xs))
     return unflatten(out)
 
 
@@ -480,12 +553,13 @@ def _sad_vals(x_f32, t_row, m: int):
     (1, M) broadcast or (BR, M) per-row template ref/array."""
     t = t_row[...]
 
-    def body(j, acc):
-        shifted = jnp.roll(x_f32, -j, axis=-1)
-        tap = jax.lax.dynamic_slice_in_dim(t, j, 1, axis=1)  # (rows, 1)
-        return acc + jnp.abs(shifted - tap.astype(jnp.float32))
+    def body(j, carry):                 # shifted = x rolled left by j
+        acc, shifted = carry
+        tap = _lane_pick(t, j)                               # (rows, 1)
+        return (acc + jnp.abs(shifted - tap.astype(jnp.float32)),
+                _roll(shifted, -1))
 
-    return jax.lax.fori_loop(0, m, body, jnp.zeros_like(x_f32))
+    return jax.lax.fori_loop(0, m, body, (jnp.zeros_like(x_f32), x_f32))[0]
 
 
 def _template_kernel(x_ref, t_ref, o_ref, *, m: int):
@@ -501,12 +575,12 @@ def template_match(data: jax.Array, template: jax.Array, *,
     return pl.pallas_call(
         functools.partial(_template_kernel, m=m),
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (i, 0)),
+        in_specs=[_each_row(n),
                   pl.BlockSpec((1, m), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, n), jnp.float32),
+        out_specs=_each_row(n),
+        out_shape=jax.ShapeDtypeStruct((r, 1, n), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(data, template.reshape(1, -1))
+    )(_as_rows3(data), template.reshape(1, -1)).reshape(r, n)
 
 
 # ---------------------------------------------------------------------------
@@ -522,9 +596,9 @@ def _substring_ends_vals(x, nee_row, m: int, idx):
     nee = nee_row[...]
 
     def body(i, state):
-        sym = jax.lax.dynamic_slice_in_dim(nee, i, 1, axis=1)  # (rows, 1)
+        sym = _lane_pick(nee, i)                               # (rows, 1)
         hit = (x == sym).astype(jnp.int32)
-        shifted = jnp.where(first, 0, jnp.roll(state, 1, axis=-1))
+        shifted = jnp.where(first, 0, _roll(state, 1))
         return jnp.where(i == 0, hit, hit * shifted)
 
     return jax.lax.fori_loop(0, m, body, jnp.zeros(x.shape, jnp.int32))
@@ -545,12 +619,12 @@ def substring_match(hay: jax.Array, needle: jax.Array, *,
     return pl.pallas_call(
         functools.partial(_substring_kernel, m=m, n=n),
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (i, 0)),
+        in_specs=[_each_row(n),
                   pl.BlockSpec((1, m), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, n), jnp.int8),
+        out_specs=_each_row(n),
+        out_shape=jax.ShapeDtypeStruct((r, 1, n), jnp.int8),
         interpret=resolve_interpret(interpret),
-    )(hay, needle.reshape(1, -1))
+    )(_as_rows3(hay), needle.reshape(1, -1)).reshape(r, n)
 
 
 # ---------------------------------------------------------------------------
@@ -571,7 +645,7 @@ def _stencil_vals(x, idx, taps: tuple[float, ...], wrap: bool, n: int):
     for k, w in enumerate(taps):        # unrolled ~M shift-mul-add cycles
         if w == 0:
             continue
-        shifted = jnp.roll(x, k - c, axis=-1)
+        shifted = _roll(x, k - c)
         if not wrap:                    # zero the lanes that wrapped around
             if k - c > 0:
                 shifted = jnp.where(idx >= k - c, shifted, 0.0)
@@ -594,11 +668,11 @@ def stencil(x: jax.Array, taps: tuple[float, ...], *, wrap: bool = True,
     return pl.pallas_call(
         functools.partial(_stencil_kernel, taps=taps, wrap=wrap),
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, n), jnp.float32),
+        in_specs=[_each_row(n)],
+        out_specs=_each_row(n),
+        out_shape=jax.ShapeDtypeStruct((r, 1, n), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(x)
+    )(_as_rows3(x)).reshape(r, n)
 
 
 # ---------------------------------------------------------------------------
@@ -616,20 +690,23 @@ def _compact_kernel(x_ref, k_ref, f_ref, o_ref, l_ref, *, n: int):
     levels = (n - 1).bit_length() if n > 1 else 0
     for b in range(levels):
         stride = 1 << b
-        sh = jnp.roll(c, stride, axis=-1)
+        sh = _roll(c, stride)
         c = c + jnp.where(idx >= stride, sh, 0)
-    new_len = c[:, n - 1:]                           # (1, 1) survivor count
-    # phase 2: src[i] = first j with c[j] >= i+1 (c is monotone, and c
-    # increments exactly at kept lanes) — a vectorized lower-bound search,
-    # one take_along_axis probe per bit, ~log2(n) more concurrent cycles
-    t = idx + 1
-    pos = jnp.zeros((1, n), jnp.int32)
-    for b in reversed(range(n.bit_length())):
-        npos = pos + (1 << b)
-        cv = jnp.take_along_axis(c, jnp.clip(npos - 1, 0, n - 1), axis=1)
-        pos = jnp.where((npos <= n) & (cv < t), npos, pos)
-    gathered = jnp.take_along_axis(x, jnp.clip(pos, 0, n - 1), axis=1)
-    o_ref[...] = jnp.where(t <= new_len, gathered, f_ref[0, 0])
+    new_len = jnp.sum(keep, axis=-1, keepdims=True)  # (1, 1) survivor count
+    # phase 2: kept lane i moves left by d = i + 1 - c[i], the number of
+    # dropped lanes before it — one roll per bit of d, low bit first,
+    # ~log2(n) more concurrent cycles.  Two movers never land on one lane: two kept
+    # lanes k apart differ in d by less than k, and after the bits below b
+    # their remaining offsets differ by a multiple of 2**b.
+    v, d, live = x, idx + 1 - c, keep
+    for b in range(levels):
+        stride = 1 << b
+        moves = live * ((d >> b) & 1)                # int32 0/1 masks
+        arrive = (_roll(moves, -stride) != 0) & (idx < n - stride)
+        v = jnp.where(arrive, _roll(v, -stride), v)
+        d = jnp.where(arrive, _roll(d, -stride), d)
+        live = jnp.where(arrive, 1, live - moves)
+    o_ref[...] = jnp.where(idx < new_len, v, f_ref[0, 0])
     l_ref[...] = new_len
 
 
@@ -645,16 +722,14 @@ def compact(x: jax.Array, keep: jax.Array, fill=0, *,
     out, nl = pl.pallas_call(
         functools.partial(_compact_kernel, n=n),
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (i, 0)),
-                  pl.BlockSpec((1, n), lambda i: (i, 0)),
+        in_specs=[_each_row(n), _each_row(n),
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((1, n), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((r, n), x.dtype),
-                   jax.ShapeDtypeStruct((r, 1), jnp.int32)],
+        out_specs=[_each_row(n), _each_row(1)],
+        out_shape=[jax.ShapeDtypeStruct((r, 1, n), x.dtype),
+                   jax.ShapeDtypeStruct((r, 1, 1), jnp.int32)],
         interpret=resolve_interpret(interpret),
-    )(x, keep.astype(jnp.int32), fill_arr)
-    return out, nl[:, 0]
+    )(_as_rows3(x), _as_rows3(keep.astype(jnp.int32)), fill_arr)
+    return out.reshape(r, n), nl.reshape(r)
 
 
 # ---------------------------------------------------------------------------
@@ -679,14 +754,14 @@ def gather_rows(x: jax.Array, idx: jax.Array, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(k,),
-        in_specs=[pl.BlockSpec((1, n), lambda i, iref: (iref[i], 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i, iref: (i, 0)))
+        in_specs=[_row_spec(n, lambda i, iref: (iref[i], 0, 0))],
+        out_specs=_row_spec(n, lambda i, iref: (i, 0, 0)))
     return pl.pallas_call(
         _copy_row_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((k, 1, n), x.dtype),
         interpret=resolve_interpret(interpret),
-    )(idx.astype(jnp.int32), x)
+    )(idx.astype(jnp.int32), _as_rows3(x)).reshape(k, n)
 
 
 def _scatter_row_kernel(inv_ref, d_ref, s_ref, o_ref):
@@ -711,16 +786,16 @@ def scatter_rows(dst: jax.Array, idx: jax.Array, src: jax.Array, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(r,),
-        in_specs=[pl.BlockSpec((1, n), lambda i, iref: (i, 0)),
-                  pl.BlockSpec((1, n),
-                               lambda i, iref: (jnp.maximum(iref[i], 0), 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i, iref: (i, 0)))
+        in_specs=[_row_spec(n, lambda i, iref: (i, 0, 0)),
+                  _row_spec(n, lambda i, iref: (jnp.maximum(iref[i], 0), 0,
+                                                0))],
+        out_specs=_row_spec(n, lambda i, iref: (i, 0, 0)))
     return pl.pallas_call(
         _scatter_row_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, n), dst.dtype),
+        out_shape=jax.ShapeDtypeStruct((r, 1, n), dst.dtype),
         interpret=resolve_interpret(interpret),
-    )(inv, dst, src)
+    )(inv, _as_rows3(dst), _as_rows3(src)).reshape(r, n)
 
 
 # ---------------------------------------------------------------------------
@@ -787,9 +862,9 @@ def _fused_apply(op: str, statics, x, ul, refs, idx, n: int):
     if op == "substring_match":
         m = s["m"]
         ends = _substring_ends_vals(x, refs[0], m, idx)
-        flags = (ends > 0) & live
+        flags = ((ends > 0) & live).astype(jnp.int32)
         if s["where"] == "start":
-            flags = jnp.roll(flags, -(m - 1), axis=-1) & (idx <= n - m)
+            flags = jnp.where(idx <= n - m, _roll(flags, -(m - 1)), 0)
         return x, ul, flags.astype(jnp.int8)
     if op == "template_match":
         m = s["m"]
@@ -864,27 +939,41 @@ def fused_stream(x: jax.Array, used_len: jax.Array, instrs, operands, *,
         o_x[...] = xv
         o_ul[...] = jnp.broadcast_to(jnp.asarray(ul, jnp.int32), (br, 1))
 
-    def _spec(rows, k):
-        if rows == 1 and rp != 1:
-            return pl.BlockSpec((1, k), lambda i: (0, 0))
-        return pl.BlockSpec((br, k), lambda i: (i, 0))
+    # br == 1 takes the (R, 1, N) row layout; a taller block satisfies
+    # Mosaic's (8, 128) rule itself when br is a multiple of 8 or all rows
+    rows3 = br == 1
 
-    in_specs = [pl.BlockSpec((br, n), lambda i: (i, 0)),
-                pl.BlockSpec((br, 1), lambda i: (i, 0))]
-    in_specs += [_spec(*a.shape) for a in operands]
-    out_specs = ([pl.BlockSpec((br, n), lambda i: (i, 0)),
-                  pl.BlockSpec((br, 1), lambda i: (i, 0))]
-                 + [pl.BlockSpec((br, n), lambda i: (i, 0))
-                    for _ in prod_dts])
-    out_shape = ([jax.ShapeDtypeStruct((rp, n), x.dtype),
-                  jax.ShapeDtypeStruct((rp, 1), jnp.int32)]
-                 + [jax.ShapeDtypeStruct((rp, n), dt) for dt in prod_dts])
+    def per_row(a):
+        """A per-row (rp, k) array as the kernel takes it, with its spec."""
+        k = a.shape[-1]
+        if rows3:
+            return _as_rows3(a), _row_spec(k, lambda i: (i, 0, 0))
+        return a, pl.BlockSpec((br, k), lambda i: (i, 0))
+
+    def per_row_out(k, dt):
+        if rows3:
+            return (jax.ShapeDtypeStruct((rp, 1, k), dt),
+                    _row_spec(k, lambda i: (i, 0, 0)))
+        return (jax.ShapeDtypeStruct((rp, k), dt),
+                pl.BlockSpec((br, k), lambda i: (i, 0)))
+
+    args, in_specs = [], []
+    for a in (x, ul2) + tuple(operands):
+        if a.shape[0] == 1 and rp != 1:           # broadcast operand
+            arr, spec = a, pl.BlockSpec(a.shape, lambda i: (0, 0))
+        else:
+            arr, spec = per_row(a)
+        args.append(arr)
+        in_specs.append(spec)
+    outs = ([per_row_out(n, x.dtype), per_row_out(1, jnp.int32)]
+            + [per_row_out(n, dt) for dt in prod_dts])
     out = pl.pallas_call(
         kernel,
         grid=(rp // br,),
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=[spec for _, spec in outs],
+        out_shape=[shape for shape, _ in outs],
         interpret=resolve_interpret(interpret),
-    )(x, ul2, *operands)
+    )(*args)
+    out = [o.reshape(rp, o.shape[-1]) for o in out]
     return out[0][:r], out[1][:r, 0], [o[:r] for o in out[2:]]

@@ -8,7 +8,7 @@ sequential on TPU, so the kv axis is the in-order accumulation axis).
 
 Supports causal masking, local (sliding-window) masking and GQA head
 grouping via the kv BlockSpec index map.  Validated against ``ref.py`` in
-interpret mode (this container is CPU-only; TPU is the lowering target).
+interpret mode off the chip; TPU is the lowering target.
 """
 
 from __future__ import annotations
@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .cpm_kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -73,12 +75,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (B, H, Sq, D); k, v: (B, KVH, Skv, D) with H % KVH == 0.
 
     Returns (B, H, Sq, D).  ``window`` masks cols <= rows - window (local
-    attention, RecurrentGemma-style).  ``interpret=True`` runs the kernel
-    body on CPU; on TPU pass interpret=False.
+    attention, RecurrentGemma-style).  ``interpret=None`` runs the kernel
+    compiled on a TPU and under the Pallas interpreter elsewhere.
     """
     b, h, sq, d = q.shape
     _, kvh, skv, _ = k.shape
@@ -112,5 +114,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denominator
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

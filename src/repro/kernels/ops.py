@@ -1,22 +1,44 @@
 """jit'd public wrappers for every kernel, with backend dispatch.
 
 ``impl`` selects: "pallas" (TPU lowering, interpret=False), "interpret"
-(Pallas body executed on CPU — the validation path in this container), or
+(Pallas body executed on CPU — the validation path off the chip), or
 "ref" (pure-jnp oracle, also the dry-run lowering so the roofline reflects
-the tiled dataflow rather than interpret-mode callbacks).
+the tiled dataflow rather than interpret-mode callbacks).  ``None`` picks
+by platform (``cpm_kernels.pallas_native``): "pallas" on a TPU, "ref"
+elsewhere.
 """
 
 from __future__ import annotations
+
+import contextlib
+import contextvars
 
 import jax
 
 from . import cpm_kernels, flash_attention as fa, ref
 
-DEFAULT_IMPL = "ref"          # CPU container default; TPU deployments: "pallas"
+_IMPL: contextvars.ContextVar[str | None] = contextvars.ContextVar(
+    "impl", default=None)
+
+
+@contextlib.contextmanager
+def use_impl(impl: str):
+    """Trace the enclosed calls with ``impl`` in place of the platform
+    default — e.g. ``with use_impl("ref"): jax.jit(lm.prefill)(...)`` to
+    check the Pallas path against the jnp oracle on the same chip.  It
+    acts at trace time, so wrap a fresh trace, not a cached jit."""
+    token = _IMPL.set(impl)
+    try:
+        yield
+    finally:
+        _IMPL.reset(token)
 
 
 def _mode(impl):
-    return DEFAULT_IMPL if impl is None else impl
+    impl = impl if impl is not None else _IMPL.get()
+    if impl is None:
+        return "pallas" if cpm_kernels.pallas_native() else "ref"
+    return impl
 
 
 def attention(q, k, v, *, causal=True, window=None, impl=None, **kw):

@@ -11,6 +11,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.distributed import sharding as shlib
 from repro.launch.mesh import make_host_mesh
@@ -34,6 +35,7 @@ def main():
                     help="prompt-lookup draft length (batched; greedy only)")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -16,6 +16,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import SHAPES, get_config
 from repro.distributed import sharding as shlib
 from repro.launch.mesh import make_host_mesh, make_production_mesh
@@ -45,6 +46,7 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

@@ -628,7 +628,7 @@ def slstm_fwd(p: Params, x: jax.Array, cfg: ModelConfig, with_cache=False):
     from repro.distributed.sharding import current_ctx
     ctx = current_ctx()
     if ctx.mesh is not None:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         dp = ctx.dp
         local_scan = shard_map(
@@ -636,7 +636,7 @@ def slstm_fwd(p: Params, x: jax.Array, cfg: ModelConfig, with_cache=False):
             in_specs=(P(dp, None, None), P(None, None, None)),
             out_specs=(P(dp, None, None),
                        tuple(P(dp, None, None) for _ in range(4))),
-            check_rep=False)
+            check_vma=False)
     out, state = local_scan(x_pre, rec_w)
     y = shard(out.astype(dt) @ p["w_down"].astype(dt), "btd")
     if not with_cache:

@@ -181,11 +181,10 @@ def audit(prog, device, section: int | None = None,
     instruction.  Refuses to run inside an active jax trace (timing and
     tracing there would be staged, not real — the PR-6 rule).
     """
-    import jax
-
+    from repro.cpm import tuning
     from repro.cpm.program import executors, introspect
     from repro.cpm.program.scheduler import instruction_steps
-    if not jax.core.trace_state_clean():
+    if not tuning.measurable():
         raise RuntimeError(
             "cycles.audit() inside an active jax trace would measure "
             "staged tracing, not execution; audit eagerly between "

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.cpm.reference import searchable
+from repro.kernels.cpm_kernels import resolve_backend
 from repro.models import lm
 from . import kv_cache, program_paths, sampling
 
@@ -75,7 +76,7 @@ class Engine:
     """Batched scan engine (static batch, fixed shapes, one program/call)."""
 
     def __init__(self, cfg: ModelConfig, params, max_len: int = 512,
-                 jit: bool = True, cpm_backend: str = "reference",
+                 jit: bool = True, cpm_backend: str | None = None,
                  cpm_interpret: bool | None = None):
         self.cfg = cfg
         self.params = params
@@ -84,8 +85,8 @@ class Engine:
         # backend for the CPM commit path (token-buffer splice):
         # "reference" keeps the one-scatter XLA lowering; "pallas" commits
         # each round through the recorded program as ONE fused_stream
-        # mega-kernel launch (see _build_commit)
-        self.cpm_backend = cpm_backend
+        # mega-kernel launch (see _build_commit).  None: by platform.
+        self.cpm_backend = resolve_backend(cpm_backend)
         self.cpm_interpret = cpm_interpret
 
         def maybe_jit(fn, **kw):

@@ -106,7 +106,7 @@ class Gateway:
                  chunk: int = 1, gen: GenConfig | None = None,
                  admit_batching: bool = True,
                  preempt: bool | PreemptConfig = True,
-                 bank_backend: str = "reference",
+                 bank_backend: str | None = None,
                  bank_interpret: bool | None = None, rng=None,
                  page_size: int | None = None,
                  pages_per_bank: int | None = None,

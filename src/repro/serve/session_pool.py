@@ -81,6 +81,7 @@ import numpy as np
 
 from repro.cpm.pool import CPMBank, MultiBankScheduler, SessionTable, SlotAllocator
 from repro.cpm.pool.sessions import ACTIVE, DONE, PARKED
+from repro.kernels.cpm_kernels import resolve_backend
 from repro.models import lm
 from repro.obs import metrics as obs_metrics
 from repro.obs import tracing as obs_tracing
@@ -168,9 +169,10 @@ class SessionPool:
     admission/retirement granularity.  ``bank_backend``/``bank_interpret``
     route the token banks ("pallas" turns each chunk's bank commit into
     one fused mega-kernel launch and sub-page moves into scalar-prefetch
-    DMA kernels).  ``admit_batching=False`` degrades admission to strict
-    one-at-a-time FIFO (buckets of one) — the baseline policy the
-    ``serve_gateway`` benchmark compares against.
+    DMA kernels; the default picks by platform, "pallas" on a TPU).
+    ``admit_batching=False`` degrades admission to strict one-at-a-time
+    FIFO (buckets of one) — the baseline policy the ``serve_gateway``
+    benchmark compares against.
     """
 
     # legacy counter attributes, now thin views over the pool's registry
@@ -189,7 +191,7 @@ class SessionPool:
     cancels = obs_metrics.series_property("cancels")
 
     def __init__(self, engine, slots: int = 8, n_banks: int = 1, gen=None,
-                 chunk: int = 1, bank_backend: str = "reference",
+                 chunk: int = 1, bank_backend: str | None = None,
                  bank_interpret: bool | None = None, rng=None,
                  admit_batching: bool = True, page_size: int | None = None,
                  pages_per_bank: int | None = None):
@@ -211,6 +213,7 @@ class SessionPool:
         self.rows_per_bank = slots // n_banks
         self.chunk = chunk
         self.max_len = engine.max_len
+        bank_backend = resolve_backend(bank_backend)
         self._bank_backend = bank_backend
         self._bank_interpret = bank_interpret
 
@@ -767,34 +770,17 @@ class SessionPool:
         the dirty sub-pages, and commit each bank's tokens via the
         scheduler's packed ``insert -> truncate`` stream — no host
         round-trip inside."""
-        engine = self.engine
         active = self.table.active()
         with obs_tracing.span("pool.decode_chunk", cat="pool",
                               vclock=self._vclock,
                               args={"chunk": self.chunk,
                                     "active": len(active)}):
-            run = engine._program("pool_chunk", self.gen, self._build_chunk,
-                                  self.slots, self.chunk, self.n_banks,
-                                  self._bank_backend, self._bank_interpret,
-                                  self.page_size, self.pages_per_bank)
+            run = self._chunk_program()
             self._rng, sub = jax.random.split(self._rng)
-            budget_left = np.zeros((self.slots,), np.int32)
-            for sess in active:
-                budget_left[sess.slot] = sess.budget - sess.emitted
-            pt = np.full((self.slots, self.C), self.total_pages, np.int32)
-            for sess in active:
-                ids = self.alloc.pages(sess.slot)
-                pt[sess.slot, :len(ids)] = ids
-            datas = [b.data for b in self.banks]
-            lenss = [b.lens for b in self.banks]
+            args = self._chunk_args(active, sub)
             t0 = time.perf_counter()
             (self.cur, self.caches, self.pos, datas, lenss,
-             self.tok_lens) = run(
-                engine.params, self.cur, self.caches, self.pos,
-                jnp.asarray(self.live), jnp.asarray(budget_left),
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp), datas, lenss, jnp.asarray(pt),
-                self.tok_lens, sub)
+             self.tok_lens) = run(*args)
             # dispatch wall time only — no forced device sync here (the
             # tracer must never add one; tests/test_obs.py asserts it)
             self.last_chunk_s = time.perf_counter() - t0
@@ -814,6 +800,28 @@ class SessionPool:
                                 vstep=self.decode_steps,
                                 args={"banks": self.n_banks,
                                       "streams": len(active)})
+
+    def _chunk_program(self):
+        """The compiled decode chunk of this pool's geometry."""
+        return self.engine._program(
+            "pool_chunk", self.gen, self._build_chunk, self.slots,
+            self.chunk, self.n_banks, self._bank_backend,
+            self._bank_interpret, self.page_size, self.pages_per_bank)
+
+    def _chunk_args(self, active, rng) -> tuple:
+        """The decode chunk's arguments for the ``active`` sessions."""
+        budget_left = np.zeros((self.slots,), np.int32)
+        pt = np.full((self.slots, self.C), self.total_pages, np.int32)
+        for sess in active:
+            budget_left[sess.slot] = sess.budget - sess.emitted
+            ids = self.alloc.pages(sess.slot)
+            pt[sess.slot, :len(ids)] = ids
+        return (self.engine.params, self.cur, self.caches, self.pos,
+                jnp.asarray(self.live), jnp.asarray(budget_left),
+                jnp.asarray(self._temp), jnp.asarray(self._topk),
+                jnp.asarray(self._topp), [b.data for b in self.banks],
+                [b.lens for b in self.banks], jnp.asarray(pt),
+                self.tok_lens, rng)
 
     def _build_chunk(self, slots: int, chunk: int, n_banks: int,
                      bank_backend: str, bank_interpret, page_size: int,

@@ -41,11 +41,16 @@ except ImportError:
         return _Strategy(lambda r: r.random() < 0.5)
 
     def floats(min_value=0.0, max_value=1.0, allow_nan=False,
-               allow_infinity=False, width=64):
+               allow_infinity=False, width=64, allow_subnormal=True):
+        ftype = _np.float32 if width == 32 else _np.float64
+        tiny = float(_np.finfo(ftype).tiny)
+
         def draw(r):
             v = r.uniform(min_value, max_value)
             if width == 32:
                 v = float(_np.float32(v))
+            if not allow_subnormal and 0 < abs(v) < tiny:
+                v = 0.0
             return v
         return _Strategy(draw)
 

@@ -17,12 +17,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from functools import partial
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.cpm import collectives
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 x = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
 
 # ring all-reduce (R7-faithful) == psum
@@ -33,7 +33,7 @@ want = np.tile(np.asarray(x).reshape(2, 4, 4).sum(1, keepdims=True), (1, 4, 1)).
 # careful: in_specs shards rows over "data" only -> each data rank holds 2 rows;
 # ring_allreduce sums across the 4 data ranks (pod axis unsharded -> replicated rows)
 x2 = jnp.arange(16, dtype=jnp.float32).reshape(4, 4)
-mesh1 = jax.make_mesh((4,), ("data",))
+mesh1 = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 f1 = shard_map(lambda v: collectives.ring_allreduce(v, "data"),
                mesh=mesh1, in_specs=jax.sharding.PartitionSpec("data", None),
                out_specs=jax.sharding.PartitionSpec("data", None))
