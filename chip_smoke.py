@@ -162,7 +162,7 @@ def one_chip(cfg, seed: int, jax, jnp) -> None:
         events = clock.events
         rep = gw.tick()
         n_ticks += 1
-        if rep.admitted == 0 and rep.chunk_wall_s > 0:
+        if rep.admitted == 0 and rep.emitted > 0:      # a decode chunk ran
             if clock.events == events:
                 steady.append(rep.wall_s)
             else:                        # retirements compile gathers anew

@@ -69,7 +69,7 @@ def main():
             print(f"tick {rep.tick:3d} @step {rep.step:3d}: "
                   f"admitted={rep.admitted} restored={rep.restored} "
                   f"preempted={rep.preempted} finished={rep.finished} "
-                  f"chunk={rep.chunk_wall_s * 1e3:.1f}ms")
+                  f"tick={rep.wall_s * 1e3:.1f}ms")
 
     # -- export 1: the Chrome/Perfetto trace --------------------------------
     here = os.path.join(os.path.dirname(os.path.dirname(

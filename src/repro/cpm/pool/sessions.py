@@ -15,6 +15,7 @@ table).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 WAITING = "waiting"
@@ -39,6 +40,7 @@ class Session:
     parks: int = 0                     # times preempted
     admit_step: int = -1               # pool.decode_steps at last (re-)admission
     first_admit_step: int = -1         # ... at FIRST admission (TTFT anchor)
+    seated_s: float = math.nan         # perf_counter at FIRST admission
 
     @property
     def finished(self) -> bool:

@@ -5,8 +5,9 @@
     metrics  ── process-global registry: counters/gauges/histograms with
     │           labeled series; JSON snapshot + Prometheus text exposition
     tracing  ── nestable spans in wall-clock AND virtual decode-step time
-    │           (gateway tick, admission, prefill, decode chunk, park/
-    │           restore), recorded host-side between compiled calls
+    │           (gateway tick and publish, admission, prefill, decode
+    │           chunk, row reads, park/restore), recorded host-side
+    │           between compiled calls, each also a profiler annotation
     export   ── Chrome/Perfetto trace_event JSON + snapshot writers
     cycles   ── per-op-family predicted-vs-measured cycle ledger hooked
                 into ``CPMProgram.steps_report()`` (model drift metric)

@@ -24,6 +24,20 @@ lookup per call and the event buffer never grows.
 Spans nest per-thread (the gateway's tick worker thread gets its own
 stack and its events carry its tid), and :mod:`repro.obs.export` renders
 the buffer as Chrome/Perfetto ``trace_event`` JSON.
+
+Every span and instant also goes to a second sink: a
+``jax.profiler.TraceAnnotation`` of the same name, with its arguments as
+scalars or short strings.  While a ``jax.profiler`` trace runs, the
+profiler stamps it on its host plane, on the thread that ran it and on
+the clock it aligns with the device planes, so a device gap can be
+blamed on the host work open across it.  With no trace running an
+annotation costs about a microsecond.  ``REPRO_OBS=0`` turns both sinks
+off.
+
+Backend compiles are recorded too: a ``jax.monitoring`` listener turns
+each (a compile, or a load from the persistent compile cache) into one
+``runtime.compile`` instant, with its seconds and the span open on the
+compiling thread, and counts it in ``repro_runtime_compiles_total``.
 """
 
 from __future__ import annotations
@@ -35,7 +49,12 @@ import threading
 import time
 from typing import Any, Callable
 
-from .metrics import enabled
+import jax
+from jax.profiler import TraceAnnotation
+
+from .metrics import counter, enabled
+
+_ANNOTATION_STR = 64               # longest string argument an annotation takes
 
 
 @dataclasses.dataclass
@@ -82,8 +101,20 @@ class Tracer:
         self._local = threading.local()
         self._lock = threading.Lock()
 
+    def _stack(self) -> list[str]:
+        """Names of the spans open on this thread, outermost first."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def _depth(self) -> int:
-        return getattr(self._local, "depth", 0)
+        return len(self._stack())
+
+    def open_span(self) -> str | None:
+        """The innermost span open on the calling thread, if any."""
+        stack = self._stack()
+        return stack[-1] if stack else None
 
     @property
     def max_events(self) -> int | None:
@@ -126,17 +157,27 @@ class Tracer:
         if not enabled():
             yield _NULL_HANDLE
             return
-        depth = self._depth()
-        self._local.depth = depth + 1
+        stack = self._stack()
+        depth = len(stack)
+        stack.append(name)
         handle = _SpanHandle(dict(args) if args else {})
+        first = args or {}
         v0 = int(vclock()) if vclock is not None else None
         t0 = time.perf_counter()
+        ann = _annotation(name, first)
+        ann.__enter__()
         try:
             yield handle
         finally:
             dur = time.perf_counter() - t0
+            if TraceAnnotation.is_enabled():
+                later = {k: v for k, v in handle.args.items()
+                         if k not in first or first[k] is not v}
+                if later:
+                    ann.set_metadata(**_scalars(later))
+            ann.__exit__(None, None, None)
             v1 = int(vclock()) if vclock is not None else None
-            self._local.depth = depth
+            del stack[depth:]
             ev = SpanEvent(name=name, cat=cat, ts=t0, dur=dur,
                            tid=threading.get_ident(), depth=depth,
                            vstep=v0,
@@ -147,9 +188,11 @@ class Tracer:
     def instant(self, name: str, cat: str = "serve",
                 vstep: int | None = None,
                 args: dict[str, Any] | None = None) -> None:
-        """Record a zero-duration marker (page grants, packed commits)."""
+        """Record a zero-duration marker (a finished request, a compile)."""
         if not enabled():
             return
+        with _annotation(name, args):
+            pass
         ev = SpanEvent(name=name, cat=cat, ts=time.perf_counter(),
                        dur=None, tid=threading.get_ident(),
                        depth=self._depth(),
@@ -180,8 +223,51 @@ class Tracer:
             self.events.clear()
 
 
+def _scalar(v):
+    """An annotation argument: a number as it is, anything else as a
+    short string (lists space-separated; ``,``, ``#`` and ``=`` would cut
+    the profiler's encoding of the arguments)."""
+    if isinstance(v, (bool, int, float)):
+        return v
+    if isinstance(v, (list, tuple)):
+        v = " ".join(str(x) for x in v)
+    v = str(v)
+    for c in ",#=":
+        v = v.replace(c, " ")
+    return v[:_ANNOTATION_STR]
+
+
+def _scalars(args: dict[str, Any]) -> dict[str, Any]:
+    return {k: _scalar(v) for k, v in args.items() if v is not None}
+
+
+def _annotation(name: str, args: dict[str, Any] | None) -> TraceAnnotation:
+    """The profiler's twin of a span; its arguments are converted only
+    while a trace is running."""
+    if args and TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **_scalars(args))
+    return TraceAnnotation(name)
+
+
 #: the process-global tracer the serving layers record through
 TRACER = Tracer()
+
+_COMPILES = counter("repro_runtime_compiles_total",
+                    "backend compiles (or persistent-cache loads)")
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    """``jax.monitoring`` listener: one ``runtime.compile`` instant per
+    backend compile, naming the span open on the compiling thread."""
+    if not event.endswith("backend_compile_duration"):
+        return
+    _COMPILES.default.inc()
+    TRACER.instant("runtime.compile", cat="runtime",
+                   args={"seconds": float(duration),
+                         "span": TRACER.open_span() or ""})
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def span(name: str, cat: str = "serve",

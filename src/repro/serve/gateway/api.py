@@ -22,7 +22,10 @@ deterministic core:
 Per-request knobs ride on :class:`Request`: a GenConfig override
 (sampling params realized per pool row), a token budget, and an optional
 ``deadline_steps`` SLO — attainment is graded in virtual decode-step
-time, so results are deterministic and machine-independent.
+time, so results are deterministic and machine-independent.  Beside the
+steps each request carries four ``perf_counter`` stamps (submitted,
+seated, first stream put, finished), recorded at finish or cancel as one
+``gateway.request`` event under its ``rid`` and ``sid``.
 """
 
 from __future__ import annotations
@@ -30,11 +33,14 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import itertools
+import math
+import time
 from typing import Any, AsyncIterator
 
 import numpy as np
 
 from repro.obs import metrics as obs_metrics
+from repro.obs import tracing as obs_tracing
 
 from ..engine import GenConfig
 from .loop import EngineLoop, TickReport
@@ -57,7 +63,8 @@ _GW_FAMILIES = {
 
 @dataclasses.dataclass
 class Request:
-    """One request's lifecycle record (all times in decode steps)."""
+    """One request's lifecycle record: times in decode steps (what SLOs
+    are graded in) and wall stamps in ``perf_counter`` seconds."""
     rid: int
     prompt: np.ndarray
     gen: GenConfig
@@ -70,6 +77,10 @@ class Request:
     finish_step: int = -1
     parks: int = 0                     # times preempted
     cancelled: bool = False
+    submitted_s: float = math.nan      # queued by submit
+    seated_s: float = math.nan         # first seated (first_admit_step)
+    first_stream_s: float = math.nan   # first non-empty put on its stream
+    finished_s: float = math.nan       # finished or cancelled
     _sent: int = 0                     # stream cursor into tokens
     _stream: Any = None                # asyncio.Queue while streaming
     _done_ev: Any = None               # asyncio.Event for aresult waiters
@@ -158,7 +169,8 @@ class Gateway:
         req = Request(rid=self._next_rid, prompt=np.asarray(prompt),
                       gen=gen if gen is not None else self.gen,
                       budget=sess.budget, deadline_steps=deadline_steps,
-                      arrival_step=self.now, sid=sid)
+                      arrival_step=self.now, sid=sid,
+                      submitted_s=time.perf_counter())
         self._next_rid += 1
         self._obs_series["requests_total"].inc()
         self._requests[req.rid] = req
@@ -201,8 +213,7 @@ class Gateway:
         sess = self.loop._finished.pop(req.sid, None)
         req.cancelled = True
         if sess is not None:
-            req.first_admit_step = sess.first_admit_step
-            req.parks = sess.parks
+            self._take_session(req, sess)
         self._finish(req, np.asarray(toks))
         return req.tokens
 
@@ -240,31 +251,59 @@ class Gateway:
         if req._done_ev is not None:
             req._done_ev.set()
         self._push_stream(req, final=True)
+        req.finished_s = time.perf_counter()
+        stamps = {k: getattr(req, k) for k in (
+            "submitted_s", "seated_s", "first_stream_s", "finished_s")}
+        obs_tracing.instant("gateway.request", cat="gateway",
+                            vstep=self.now, args={
+                                "rid": req.rid, "sid": req.sid,
+                                # a stamp never taken is null, not NaN,
+                                # so exported traces stay valid JSON
+                                **{k: None if math.isnan(v) else v
+                                   for k, v in stamps.items()}})
+
+    @staticmethod
+    def _take_session(req: Request, sess) -> None:
+        """What the request keeps of its finished session."""
+        req.first_admit_step = sess.first_admit_step
+        req.seated_s = sess.seated_s
+        req.parks = sess.parks
 
     def _publish(self) -> None:
-        for sid, sess in self.loop.take_finished().items():
-            req = self._by_sid.get(sid)
-            if req is None:
-                continue                   # cancelled out-of-band
-            req.first_admit_step = sess.first_admit_step
-            req.parks = sess.parks
-            self._finish(req, np.asarray(sess.tokens))
-        for rid in list(self._streaming):
-            req = self._requests.get(rid)
-            if req is None or req.done:
-                continue
-            self._push_stream(req, final=False)
+        with obs_tracing.span("gateway.publish", cat="gateway",
+                              vclock=self.pool._vclock) as sp:
+            finished = pushed = 0
+            for sid, sess in self.loop.take_finished().items():
+                req = self._by_sid.get(sid)
+                if req is None:
+                    continue               # cancelled out-of-band
+                self._take_session(req, sess)
+                self._finish(req, np.asarray(sess.tokens))
+                finished += 1
+            for rid in list(self._streaming):
+                req = self._requests.get(rid)
+                if req is None or req.done:
+                    continue
+                pushed += self._push_stream(req, final=False)
+            sp.args["streams"] = pushed
+            sp.args["finished"] = finished
 
-    def _push_stream(self, req: Request, final: bool) -> None:
+    def _push_stream(self, req: Request, final: bool) -> bool:
+        """Put the request's new tokens (and at ``final`` its end) on its
+        stream; True if tokens were put."""
         if req._stream is None:
-            return
+            return False
         toks = req.tokens if final else self.pool.peek_tokens(req.sid)
-        if len(toks) > req._sent:
+        put = len(toks) > req._sent
+        if put:
+            if math.isnan(req.first_stream_s):
+                req.first_stream_s = time.perf_counter()
             req._stream.put_nowait(np.asarray(toks[req._sent:]))
             req._sent = len(toks)
         if final:
             req._stream.put_nowait(None)
             self._streaming.discard(req.rid)
+        return put
 
     # -- asyncio face --------------------------------------------------------
     def _ensure_wake(self) -> asyncio.Event:

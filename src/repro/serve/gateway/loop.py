@@ -22,7 +22,8 @@ The loop is deliberately synchronous and deterministic — virtual time is
 the pool's ``decode_steps`` — so benchmarks and identity tests drive it
 tick by tick; the asyncio front door (``gateway.api``) wraps it
 cooperatively.  Every tick records a ``gateway.tick`` span (wall +
-virtual clock) through :mod:`repro.obs.tracing`.
+virtual clock) through :mod:`repro.obs.tracing`; the pool's spans
+(admission, page top-up, decode-chunk dispatch, retirement) nest in it.
 """
 
 from __future__ import annotations
@@ -50,10 +51,10 @@ class TickReport:
     preempted       sessions parked this tick (policy + page stalls)
     finished        sessions retired into the delivery buffer this tick
     emitted         tokens emitted this tick (prefill + decode), all rows
-    chunk_wall_s    wall seconds dispatching this tick's compiled decode
-                    chunk (0.0 when no chunk ran; dispatch only — the loop
-                    never forces a device sync)
-    wall_s          wall seconds of the whole tick (preempt+step+collect)
+    wall_s          wall seconds of the whole tick (preempt+step+collect;
+                    the loop adds no device sync, so the decode chunk's
+                    device time is only in a profiler trace, and its
+                    dispatch in the ``pool.decode_chunk`` span)
     active          sessions decoding after the tick
     waiting         fresh sessions still queued after the tick
     parked          preempted sessions queued after the tick
@@ -73,7 +74,6 @@ class TickReport:
     preempted: int
     finished: int
     emitted: int
-    chunk_wall_s: float
     wall_s: float
     active: int
     waiting: int
@@ -128,7 +128,6 @@ class EngineLoop:
             preempted=pool.preemptions - before["preemptions"],
             finished=len(self._finished) - done_before,
             emitted=pool.total_emitted - before["total_emitted"],
-            chunk_wall_s=pool.last_chunk_s,
             wall_s=time.perf_counter() - t0,
             active=stats["active"],
             waiting=stats["waiting"],

@@ -129,10 +129,6 @@ _POOL_FAMILIES = (
     | {k: obs_metrics.gauge(name, help, ("pool",))
        for k, (name, help) in _POOL_GAUGES.items()}
 )
-_CHUNK_SECONDS = obs_metrics.histogram(
-    "repro_pool_chunk_seconds",
-    "wall seconds per compiled decode chunk (dispatch, no forced sync)",
-    ("pool",))
 
 
 @dataclasses.dataclass
@@ -261,8 +257,6 @@ class SessionPool:
         self._pool_label = str(next(_POOL_IDS))
         self._obs_series = {k: fam.labels(pool=self._pool_label)
                             for k, fam in _POOL_FAMILIES.items()}
-        self._chunk_hist = _CHUNK_SECONDS.labels(pool=self._pool_label)
-        self.last_chunk_s = 0.0            # wall time of the last chunk
 
     # -- paging arithmetic --------------------------------------------------
     def pages_for(self, tokens: int) -> int:
@@ -333,7 +327,6 @@ class SessionPool:
     def step(self) -> dict:
         """Admit -> decode ``chunk`` tokens for every live session ->
         retire.  Returns a stats snapshot (see :meth:`stats`)."""
-        self.last_chunk_s = 0.0             # this step's chunk wall time
         self._admit()
         self._retire()                      # budget-1 sessions finish on admit
         if self.table.active_count():
@@ -424,6 +417,7 @@ class SessionPool:
         if not take:
             return
         seated: dict[int, int] = {}
+        granted = 0
         for sess in self.table.peek_waiting(take):
             need = (sess.parked.n_pages if sess.phase == PARKED
                     else self._grant0(sess.prompt_len))
@@ -432,14 +426,13 @@ class SessionPool:
                 continue                    # stays queued, FIFO order kept
             seated[sess.sid] = slot
             self._free_hint -= 1
-            obs_tracing.instant("pool.page_grant", cat="pool",
-                                vstep=self.decode_steps,
-                                args={"slot": slot, "pages": need})
+            granted += need
         if not seated:
             return
         with obs_tracing.span("pool.admission", cat="pool",
                               vclock=self._vclock,
-                              args={"seated": len(seated)}) as sp:
+                              args={"seated": len(seated),
+                                    "pages": granted}) as sp:
             plan = admission.plan(
                 [s for s in self.table.peek_waiting(take)
                  if s.sid in seated],
@@ -456,6 +449,7 @@ class SessionPool:
         sess.admit_step = self.decode_steps
         if sess.first_admit_step < 0:
             sess.first_admit_step = self.decode_steps
+            sess.seated_s = time.perf_counter()
         self.live[slot] = True
         self._temp[slot] = sess.gen.temperature
         self._topk[slot] = sess.gen.top_k
@@ -508,7 +502,8 @@ class SessionPool:
         k, s = len(bucket), bucket[0].prompt_len
         ctx = obs_tracing.span("pool.admit_bucket", cat="pool",
                                vclock=self._vclock,
-                               args={"sessions": k, "prompt_len": s})
+                               args={"sessions": k, "prompt_len": s,
+                                     "sids": [se.sid for se in bucket]})
         with ctx:
             self._admit_bucket_inner(bucket, seated, k, s)
 
@@ -593,7 +588,7 @@ class SessionPool:
         n_live = self.pages_for(row_len)
         with obs_tracing.span("pool.park", cat="pool", vclock=self._vclock,
                               args={"sid": sid, "pages": n_live}):
-            row = self._read_row(sess)
+            row = self._read_row(sess, "park")
             pt1 = jnp.asarray(
                 self._page_table_rows([slot], n_live)[:, :n_live])
             image = kv_cache.lift_slot(self.caches, self.engine.cfg, slot,
@@ -684,16 +679,21 @@ class SessionPool:
         return self.table.at_slot(slot) if slot is not None else None
 
     # -- cancellation / inspection ------------------------------------------
-    def _read_row(self, sess) -> np.ndarray:
+    def _read_row(self, sess, site: str) -> np.ndarray:
         """A session's token content reassembled from its live sub-pages
-        (host copy)."""
+        (host copy: page ids up, one bank gather, the pages down).
+        ``site`` names the caller: ``stream``, ``retire``, ``cancel`` or
+        ``park``."""
         row_len = sess.prompt_len + sess.emitted
         n_live = self.pages_for(row_len)
-        base = self._bank_of(sess.slot) * self.pages_per_bank
-        local = jnp.asarray(
-            [p - base for p in self.alloc.pages(sess.slot)[:n_live]],
-            jnp.int32)
-        pages = np.asarray(self.banks[sess.bank].gather(local))
+        with obs_tracing.span("pool.read_row", cat="pool",
+                              args={"sid": sess.sid, "pages": n_live,
+                                    "site": site}):
+            base = self._bank_of(sess.slot) * self.pages_per_bank
+            local = jnp.asarray(
+                [p - base for p in self.alloc.pages(sess.slot)[:n_live]],
+                jnp.int32)
+            pages = np.asarray(self.banks[sess.bank].gather(local))
         return pages.reshape(-1)[:row_len]
 
     def _row_committed(self, sess) -> int:
@@ -714,7 +714,7 @@ class SessionPool:
         if sess.phase == DONE:
             return np.asarray(sess.tokens)
         if sess.phase == ACTIVE:
-            row = self._read_row(sess)
+            row = self._read_row(sess, "cancel")
             self.table.finish(sid, row)
             self._release(sess.slot)
         elif sess.phase == PARKED:
@@ -730,7 +730,7 @@ class SessionPool:
         in any phase — what the gateway's streaming iterator reads."""
         sess = self.table.get(sid)
         if sess.phase == ACTIVE:
-            return self._read_row(sess)
+            return self._read_row(sess, "stream")
         if sess.phase == PARKED:
             return np.asarray(sess.parked.row[:sess.parked.row_len])
         if sess.phase == DONE:
@@ -748,21 +748,25 @@ class SessionPool:
         bounds every session's worst case to one bank's capacity)."""
         order = sorted(self.table.active(),
                        key=lambda s: (s.first_admit_step, s.sid))
-        for sess in reversed(order):        # youngest parks first if dry
-            need = min(self.C, self.pages_for(
-                sess.prompt_len + sess.emitted + self.chunk))
-            have = len(self.alloc.pages(sess.slot))
-            if need <= have:
-                continue
-            lo, hi = self._page_range(self._bank_of(sess.slot))
-            if self.alloc.alloc_pages(sess.slot, need - have,
-                                      lo, hi) is None:
-                self.page_stalls += 1
-                self.park(sess.sid)
-            else:
-                obs_tracing.instant(
-                    "pool.page_topup", cat="pool", vstep=self.decode_steps,
-                    args={"slot": sess.slot, "pages": need - have})
+        with obs_tracing.span("pool.ensure_pages", cat="pool",
+                              vclock=self._vclock) as sp:
+            topped = granted = 0
+            for sess in reversed(order):    # youngest parks first if dry
+                need = min(self.C, self.pages_for(
+                    sess.prompt_len + sess.emitted + self.chunk))
+                have = len(self.alloc.pages(sess.slot))
+                if need <= have:
+                    continue
+                lo, hi = self._page_range(self._bank_of(sess.slot))
+                if self.alloc.alloc_pages(sess.slot, need - have,
+                                          lo, hi) is None:
+                    self.page_stalls += 1
+                    self.park(sess.sid)
+                else:
+                    topped += 1
+                    granted += need - have
+            sp.args["sessions"] = topped
+            sp.args["pages"] = granted
 
     def _decode_chunk(self) -> None:
         """One compiled program: gather every session's logical row
@@ -778,13 +782,10 @@ class SessionPool:
             run = self._chunk_program()
             self._rng, sub = jax.random.split(self._rng)
             args = self._chunk_args(active, sub)
-            t0 = time.perf_counter()
+            # the span times the dispatch: no device sync here (the tracer
+            # must never add one; tests/test_obs.py asserts it)
             (self.cur, self.caches, self.pos, datas, lenss,
              self.tok_lens) = run(*args)
-            # dispatch wall time only — no forced device sync here (the
-            # tracer must never add one; tests/test_obs.py asserts it)
-            self.last_chunk_s = time.perf_counter() - t0
-            self._chunk_hist.observe(self.last_chunk_s)
             for b, d, ln in zip(self.banks, datas, lenss):
                 b.data, b.lens = d, ln
 
@@ -796,10 +797,6 @@ class SessionPool:
             self.decode_steps += self.chunk
             self.sched.bank_launches += self.n_banks  # packed commits
             self.sched.streams_packed += len(active)
-            obs_tracing.instant("pool.commit_packed", cat="pool",
-                                vstep=self.decode_steps,
-                                args={"banks": self.n_banks,
-                                      "streams": len(active)})
 
     def _chunk_program(self):
         """The compiled decode chunk of this pool's geometry."""
@@ -918,11 +915,18 @@ class SessionPool:
 
     # -- retirement ---------------------------------------------------------
     def _retire(self) -> None:
-        for sess in list(self.table.active()):
-            if not sess.finished:
-                continue
-            ln = self._row_committed(sess)
-            assert ln == sess.prompt_len + sess.emitted, (
-                ln, sess.prompt_len, sess.emitted)
-            self.table.finish(sess.sid, self._read_row(sess))
-            self._release(sess.slot)
+        """Retire the sessions that reached their budget: each one's bank
+        length read, row read and release, in one ``pool.retire`` span
+        (recorded only when something retires)."""
+        done = [s for s in self.table.active() if s.finished]
+        if not done:
+            return
+        with obs_tracing.span("pool.retire", cat="pool",
+                              vclock=self._vclock,
+                              args={"sessions": len(done)}):
+            for sess in done:
+                ln = self._row_committed(sess)
+                assert ln == sess.prompt_len + sess.emitted, (
+                    ln, sess.prompt_len, sess.emitted)
+                self.table.finish(sess.sid, self._read_row(sess, "retire"))
+                self._release(sess.slot)

@@ -15,7 +15,11 @@ The invariants are the contract that makes telemetry safe to leave on:
     unchanged.
 """
 
+import asyncio
+import glob
 import json
+import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +48,57 @@ def granite():
 def _prompt(seed, s):
     return jax.random.randint(jax.random.PRNGKey(seed), (s,), 0,
                               CFG.vocab_size)
+
+
+def _serve_streamed(gw, prompts, budget):
+    """Stream every prompt through ``gw.serve`` (streams attached before
+    the loop starts); returns ``{rid: client-side time of the first
+    token}``."""
+    import time
+
+    async def consume(rid):
+        first = math.nan
+        async for _ in gw.stream(rid):
+            if math.isnan(first):
+                first = time.perf_counter()
+        return rid, first
+
+    async def scenario():
+        rids = [gw.submit(p, budget) for p in prompts]
+        tasks = [asyncio.ensure_future(consume(r)) for r in rids]
+        await asyncio.sleep(0)             # every stream attaches
+        await gw.start()
+        got = await asyncio.gather(*tasks)
+        await gw.stop()
+        return dict(got)
+
+    return asyncio.run(scenario())
+
+
+#: the program's own span and instant names
+PROGRAM_SPANS = ("gateway.tick", "gateway.publish", "gateway.request",
+                 "pool.admission", "pool.admit_bucket", "pool.prefill",
+                 "pool.ensure_pages", "pool.decode_chunk", "pool.retire",
+                 "pool.read_row")
+
+
+def _host_events(trace_dir):
+    """``(name, stats)`` of every event on the profiler trace's host
+    plane in time order (each thread is a line of its own), read back
+    with ``jax.profiler.ProfileData``."""
+    import warnings
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    with warnings.catch_warnings():        # jaxlib's stats type warns
+        warnings.simplefilter("ignore", DeprecationWarning)
+        events = sorted(
+            [(e.start_ns, e.name, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events],
+            key=lambda ev: ev[0])
+    return [(name, stats) for _, name, stats in events]
 
 
 # ---------------------------------------------------------------------------
@@ -127,6 +182,10 @@ class TestMetrics:
         mini-parser's HELP/TYPE-ordering and histogram-consistency
         checks (the same gate CI runs on a /metrics scrape)."""
         from repro.obs import promparse
+        lat = metrics.histogram("t_exposition_seconds", "a histogram "
+                                "family beside the serving series", ())
+        for v in (0.002, 0.03, 0.7):
+            lat.observe(v)
         gw = Gateway(granite, slots=2, chunk=2)
         gw.result(gw.submit(_prompt(60, 8), 4, deadline_steps=100))
         fams = promparse.parse(metrics.REGISTRY.prometheus_text())
@@ -196,6 +255,28 @@ class TestTracing:
         tr.instant("i")
         tr.counter("c", 1)
         assert tr.spans() == []
+
+    def test_annotation_arguments_are_scalars(self):
+        """The profiler's copy of a span's arguments: numbers stay
+        numbers, lists and strings become short strings free of the
+        characters that delimit the profiler's encoding."""
+        got = tracing._scalars({"sids": [3, 4], "site": "a,b#c=d",
+                                "n": 5, "f": 1.5, "gone": None,
+                                "long": "x" * 500})
+        assert got == {"sids": "3 4", "site": "a b c d", "n": 5, "f": 1.5,
+                       "long": "x" * 64}
+
+    def test_compile_event_names_open_span(self):
+        fam = metrics.REGISTRY.get("repro_runtime_compiles_total")
+        before = fam.default.value
+        tracing.TRACER.clear()
+        x = np.arange(7.0)
+        with tracing.span("outer.work"):
+            jax.jit(lambda v: v * 3 + 1)(x).block_until_ready()
+        evs = tracing.TRACER.spans("runtime.compile")
+        assert [e.args["span"] for e in evs] == ["outer.work"]
+        assert evs[0].args["seconds"] >= 0 and evs[0].cat == "runtime"
+        assert fam.default.value == before + 1
 
     def test_thread_isolation(self):
         import threading
@@ -379,23 +460,25 @@ class TestOverheadInvariants:
         on or off — REPRO_OBS is not (and must never become) a compile
         discriminator."""
         def run_workload():
-            pool = granite.session_pool(slots=2, n_banks=1, chunk=2)
-            for i in range(2):
-                pool.submit(_prompt(500 + i, 8), 4)
-            pool.drain()
-            return {k for k in granite._programs if k[0].startswith("pool")}
+            tracing.TRACER.clear()
+            gw = Gateway(granite, slots=2, chunk=2)
+            _serve_streamed(gw, [_prompt(500 + i, 8) for i in range(3)], 4)
+            names = {e.name for e in tracing.TRACER.spans()}
+            return ({k for k in granite._programs if k[0].startswith("pool")},
+                    names)
 
         monkeypatch.setenv("REPRO_OBS", "1")
         for k in list(granite._programs):
             if k[0].startswith("pool"):
                 del granite._programs[k]
-        keys_on = run_workload()
+        keys_on, names_on = run_workload()
         monkeypatch.setenv("REPRO_OBS", "0")
         for k in list(granite._programs):
             if k[0].startswith("pool"):
                 del granite._programs[k]
-        keys_off = run_workload()
+        keys_off, names_off = run_workload()
         assert keys_on == keys_off and keys_on
+        assert set(PROGRAM_SPANS) <= names_on and not names_off
 
     def test_no_device_sync_inside_chunk(self, granite, monkeypatch):
         """Span recording must not force a device sync: zero
@@ -415,6 +498,21 @@ class TestOverheadInvariants:
         assert syncs["n"] == 0
         assert tracing.TRACER.spans("pool.decode_chunk")
 
+        # nor do the spans between chunks: page top-up, retirement, the
+        # row reads and the gateway's publish
+        monkeypatch.setattr(jax, "block_until_ready", real)
+        gw = Gateway(granite, slots=2, chunk=2)
+        gw.submit(_prompt(601, 8), 5)
+        gw.tick()
+        tracing.TRACER.clear()
+        monkeypatch.setattr(jax, "block_until_ready", counting)
+        while gw.loop.pending():
+            gw.tick()
+        assert syncs["n"] == 0
+        names = {e.name for e in tracing.TRACER.spans()}
+        assert {"pool.ensure_pages", "pool.retire", "pool.read_row",
+                "gateway.publish", "gateway.request"} <= names
+
     def test_disabled_pool_keeps_stats_but_records_no_spans(
             self, granite, monkeypatch):
         monkeypatch.setenv("REPRO_OBS", "0")
@@ -433,14 +531,22 @@ class TestOverheadInvariants:
 
 class TestServingIntegration:
     def test_tick_report_schema_and_dict_fallback(self, granite):
+        tracing.TRACER.clear()
         gw = Gateway(granite, slots=2, chunk=2)
         gw.submit(_prompt(700, 8), 4)
         rep = gw.tick()
         assert isinstance(rep, TickReport)
         assert rep.tick == 0 and rep.step == gw.pool.decode_steps
         assert rep.admitted == 1 and rep.restored == 0
-        assert rep.emitted >= 1 and rep.chunk_wall_s >= 0.0
-        assert rep.wall_s >= rep.chunk_wall_s
+        assert rep.emitted >= 1
+        # the chunk's dispatch is a span nested in the tick's span
+        (tick,) = tracing.TRACER.spans("gateway.tick")
+        (chunk,) = tracing.TRACER.spans("pool.decode_chunk")
+        assert tick.args["tick"] == rep.tick and chunk.depth > tick.depth
+        assert tick.ts <= chunk.ts
+        assert chunk.ts + chunk.dur <= tick.ts + tick.dur
+        assert rep.wall_s >= tick.dur >= chunk.dur >= 0.0
+        assert chunk.vstep + chunk.vdur == rep.step
         assert rep["waiting"] == rep.waiting          # field access
         assert rep["preemptions"] == 0                # stats fallback
         assert rep.get("no_such_key", 42) == 42
@@ -482,8 +588,72 @@ class TestServingIntegration:
                      "pool.decode_chunk", "pool.park", "pool.restore"):
             assert counts.get(name, 0) >= 1, (name, sorted(counts))
 
+    def test_request_wall_stamps_in_order(self, granite):
+        """Every request's wall stamps run submit <= seat <= first stream
+        put <= finish, the client sees its first token after the put, and
+        the ``gateway.request`` event carries the same stamps."""
+        tracing.TRACER.clear()
+        gw = Gateway(granite, slots=2, chunk=2)
+        first_seen = _serve_streamed(
+            gw, [_prompt(900 + i, 8) for i in range(5)], 5)
+        events = {e.args["rid"]: e.args
+                  for e in tracing.TRACER.spans("gateway.request")}
+        assert sorted(events) == sorted(first_seen)
+        for rid, seen in first_seen.items():
+            req = gw.request(rid)
+            stamps = [req.submitted_s, req.seated_s, req.first_stream_s,
+                      req.finished_s]
+            assert all(math.isfinite(t) for t in stamps), stamps
+            assert stamps == sorted(stamps)
+            assert req.first_stream_s <= seen
+            assert events[rid]["sid"] == req.sid
+            assert [events[rid][k] for k in (
+                "submitted_s", "seated_s", "first_stream_s",
+                "finished_s")] == stamps
+
     def test_obs_package_exports(self):
         assert obs.enabled() in (True, False)
         assert callable(obs.span) and callable(obs.audit)
         assert obs.REGISTRY is metrics.REGISTRY
         assert obs.TRACER is tracing.TRACER
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink
+# ---------------------------------------------------------------------------
+
+class TestProfilerSink:
+    def test_spans_land_on_profiler_host_plane(self, granite, tmp_path):
+        """Under ``jax.profiler.trace`` a served run's spans are on the
+        trace's host plane, with their arguments, those set inside the
+        span included."""
+        gw = Gateway(granite, slots=2, chunk=2)
+        with jax.profiler.trace(str(tmp_path)):
+            _serve_streamed(gw, [_prompt(950 + i, 8) for i in range(3)], 5)
+        events = _host_events(str(tmp_path))
+        names = {n for n, _ in events}
+        for name in ("gateway.tick", "gateway.publish", "pool.decode_chunk",
+                     "pool.read_row", "pool.admit_bucket", "gateway.request"):
+            assert name in names, (name, sorted(names)[:40])
+        reads = [st for n, st in events if n == "pool.read_row"]
+        assert {"stream", "retire"} <= {st["site"] for st in reads}
+        assert all({"sid", "pages"} <= set(st) for st in reads)
+        publish = [st for n, st in events if n == "gateway.publish"]
+        assert all({"streams", "finished"} <= set(st) for st in publish)
+        assert sum(st["finished"] for st in publish) == 3
+        ticks = [st["tick"] for n, st in events if n == "gateway.tick"]
+        assert ticks == sorted(ticks) and ticks[0] == 0
+
+    def test_disabled_records_into_neither_sink(self, granite, tmp_path,
+                                                monkeypatch):
+        monkeypatch.setenv("REPRO_OBS", "0")
+        tracing.TRACER.clear()
+        gw = Gateway(granite, slots=2, chunk=2)
+        with jax.profiler.trace(str(tmp_path)):
+            _serve_streamed(gw, [_prompt(970 + i, 8) for i in range(2)], 4)
+            tracing.TRACER.instant("an.instant")
+        names = {n for n, _ in _host_events(str(tmp_path))}
+        assert not names & (set(PROGRAM_SPANS) | {"an.instant"})
+        assert tracing.TRACER.spans() == []
+        req = gw.request(0)                # the request's own stamps stay
+        assert req.submitted_s <= req.seated_s <= req.finished_s
