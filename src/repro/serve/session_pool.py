@@ -36,11 +36,11 @@ same width, same mask as the un-paged layout), scans ``chunk`` decode
 steps, then scatters back only the *dirty* pages (ranks touched since
 the chunk started; clean pages keep their sentinel and drop).  Sessions
 are admitted with ``ceil((prompt+1)/page_size)`` pages and topped up
-host-side between chunks (``_ensure_pages``) with enough slack to cover
-the next chunk — a session crossing a page boundary mid-decode never
-stalls the compiled step.  When a bank runs dry the youngest sessions
-park (their pages free instantly), so the oldest always progresses and
-a lone session can never livelock.
+host-side between chunks (``_ensure_pages``, one page grant per bank)
+with enough slack to cover the next chunk — a session crossing a page
+boundary mid-decode never stalls the compiled step.  When a bank runs
+dry the youngest sessions park (their pages free instantly), so the
+oldest always progresses and a lone session can never livelock.
 
 Bookkeeping is CPM all the way down: free-slot and free-page lookups run
 on the allocator's metadata devices (§6 ``compare`` + Rule-6 drain,
@@ -742,29 +742,43 @@ class SessionPool:
         """Host-side top-up between chunks: every active session gets
         enough slack pages to cover the next chunk's KV and token writes
         (so a page-boundary crossing never stalls the compiled step).
-        When a bank runs dry the *youngest* sessions park — their pages
-        free instantly for the older survivors, so the oldest session
-        always progresses and a lone session can never livelock (submit
-        bounds every session's worst case to one bank's capacity)."""
+        Each bank's shortfalls, youngest session first, go out as ONE
+        grant, which hands every session the ids a one-at-a-time loop
+        would.  A bank that cannot cover them all runs that loop instead:
+        the *youngest* sessions park — their pages free instantly for the
+        older survivors, so the oldest session always progresses and a
+        lone session can never livelock (submit bounds every session's
+        worst case to one bank's capacity)."""
         order = sorted(self.table.active(),
                        key=lambda s: (s.first_admit_step, s.sid))
         with obs_tracing.span("pool.ensure_pages", cat="pool",
                               vclock=self._vclock) as sp:
-            topped = granted = 0
-            for sess in reversed(order):    # youngest parks first if dry
+            short = []                      # (sess, pages), youngest first
+            for sess in reversed(order):
                 need = min(self.C, self.pages_for(
                     sess.prompt_len + sess.emitted + self.chunk))
                 have = len(self.alloc.pages(sess.slot))
-                if need <= have:
-                    continue
-                lo, hi = self._page_range(self._bank_of(sess.slot))
-                if self.alloc.alloc_pages(sess.slot, need - have,
-                                          lo, hi) is None:
+                if need > have:
+                    short.append((sess, need - have))
+            by_bank: dict[int, list] = {}
+            for sess, k in short:
+                by_bank.setdefault(self._bank_of(sess.slot), []).append(
+                    (sess.slot, k))
+            dry = set()                     # banks that cannot cover all
+            for bank, reqs in sorted(by_bank.items()):
+                if self.alloc.grant_pages(
+                        reqs, *self._page_range(bank)) is None:
+                    dry.add(bank)
+            topped = granted = 0
+            for sess, k in short:           # youngest parks first if dry
+                bank = self._bank_of(sess.slot)
+                if bank in dry and self.alloc.alloc_pages(
+                        sess.slot, k, *self._page_range(bank)) is None:
                     self.page_stalls += 1
                     self.park(sess.sid)
                 else:
                     topped += 1
-                    granted += need - have
+                    granted += k
             sp.args["sessions"] = topped
             sp.args["pages"] = granted
 

@@ -79,7 +79,7 @@ def _serve_streamed(gw, prompts, budget):
 PROGRAM_SPANS = ("gateway.tick", "gateway.publish", "gateway.request",
                  "pool.admission", "pool.admit_bucket", "pool.prefill",
                  "pool.ensure_pages", "pool.decode_chunk", "pool.retire",
-                 "pool.read_row")
+                 "pool.read_row", "alloc.grant")
 
 
 def _host_events(trace_dir):
@@ -498,10 +498,11 @@ class TestOverheadInvariants:
         assert syncs["n"] == 0
         assert tracing.TRACER.spans("pool.decode_chunk")
 
-        # nor do the spans between chunks: page top-up, retirement, the
-        # row reads and the gateway's publish
+        # nor do the spans between chunks: page top-up and its grant,
+        # retirement, the row reads and the gateway's publish (pages of 4
+        # tokens, so the session outgrows its admission grant)
         monkeypatch.setattr(jax, "block_until_ready", real)
-        gw = Gateway(granite, slots=2, chunk=2)
+        gw = Gateway(granite, slots=2, chunk=2, page_size=4)
         gw.submit(_prompt(601, 8), 5)
         gw.tick()
         tracing.TRACER.clear()
@@ -511,7 +512,7 @@ class TestOverheadInvariants:
         assert syncs["n"] == 0
         names = {e.name for e in tracing.TRACER.spans()}
         assert {"pool.ensure_pages", "pool.retire", "pool.read_row",
-                "gateway.publish", "gateway.request"} <= names
+                "gateway.publish", "gateway.request", "alloc.grant"} <= names
 
     def test_disabled_pool_keeps_stats_but_records_no_spans(
             self, granite, monkeypatch):

@@ -235,6 +235,116 @@ class TestPagedAllocator:
             assert cpm.victim() == orc.victim()
 
 
+    @pytest.mark.parametrize("backend", ["reference", "pallas"])
+    def test_batched_grant_equals_one_at_a_time(self, backend):
+        """A batched grant of several ``(slot, k)`` requests hands out the
+        same ids, and leaves the same page file, as the same requests
+        granted one at a time — here on a fragmented bank range."""
+        kw = {"backend": backend, "interpret": True} \
+            if backend == "pallas" else {}
+        lists = []
+        for batched in (True, False):
+            a = SlotAllocator(4, n_pages=16, **kw)
+            s = [a.alloc() for _ in range(4)]
+            a.alloc_pages(s[0], 3, 8, 16)
+            a.alloc_pages(s[1], 2, 8, 16)
+            a.free(s[0])                               # frees 8, 9, 10
+            assert a.alloc() == s[0]
+            reqs = [(s[0], 2), (s[2], 3), (s[3], 1)]
+            if batched:
+                got = a.grant_pages(reqs, 8, 16)
+            else:
+                got = [a.alloc_pages(slot, k, 8, 16) for slot, k in reqs]
+            assert got == [[8, 9], [10, 13, 14], [15]]
+            lists.append(([a.pages(x) for x in s], a.page_state_vector()))
+        assert lists[0][0] == lists[1][0]
+        np.testing.assert_array_equal(lists[0][1], lists[1][1])
+
+    def test_batched_grant_that_does_not_fit_claims_nothing(self):
+        a = SlotAllocator(3, n_pages=8)
+        s = [a.alloc() for _ in range(3)]
+        a.alloc_pages(s[0], 2, 0, 4)
+        before = a.page_state_vector().copy()
+        assert a.grant_pages([(s[1], 1), (s[2], 2)], 0, 4) is None
+        np.testing.assert_array_equal(a.page_state_vector(), before)
+        assert a.pages(s[1]) == a.pages(s[2]) == []
+        assert a.page_free_count(0, 4) == 2
+        assert a.grant_pages([(s[1], 1), (s[2], 1)], 0, 4) == [[2], [3]]
+        assert a.page_free_count(0, 4) == 0
+
+    @given(st.lists(st.tuples(st.integers(0, 4), st.integers(0, 7)),
+                    min_size=1, max_size=60))
+    @settings(max_examples=20, deadline=None)
+    def test_batched_grant_traces_match_oracle(self, moves):
+        """Random traces with a batched-grant move beside alloc, one-page
+        list growth, free and touch: identical grants (incl. both-None)
+        and page lists as the oracle, nothing owned twice or leaked."""
+        n, npg = 3, 8
+        cpm = SlotAllocator(n, n_pages=npg)
+        orc = OracleAllocator(n, n_pages=npg)
+        held: set[int] = set()
+        for i, (mv, arg) in enumerate(moves):
+            lo = (arg % 2) * (npg // 2)                   # one bank's range
+            if mv == 0:                                   # alloc slot
+                got = cpm.alloc()
+                assert got == orc.alloc()
+                if got is not None:
+                    held.add(got)
+            elif mv == 1 and held:                        # extend one list
+                slot = sorted(held)[i % len(held)]
+                k = 1 + arg % 3
+                assert (cpm.alloc_pages(slot, k, lo, lo + npg // 2)
+                        == orc.alloc_pages(slot, k, lo, lo + npg // 2))
+            elif mv == 2 and held:                        # free
+                slot = sorted(held)[i % len(held)]
+                cpm.free(slot)
+                orc.free(slot)
+                held.discard(slot)
+            elif mv == 3 and held:                        # touch
+                slot = sorted(held)[i % len(held)]
+                cpm.touch(slot)
+                orc.touch(slot)
+            elif mv == 4 and held:                        # batched top-up
+                reqs = [(slot, 1 + (arg + j) % 2)
+                        for j, slot in enumerate(sorted(held))]
+                assert (cpm.grant_pages(reqs, lo, lo + npg // 2)
+                        == orc.grant_pages(reqs, lo, lo + npg // 2))
+            owned = [p for s in held for p in orc.pages(s)]
+            assert len(owned) == len(set(owned))
+            for s in sorted(held):
+                assert cpm.pages(s) == orc.pages(s)
+            assert (cpm.page_free_count() == orc.page_free_count()
+                    == npg - len(owned))
+            assert set(np.flatnonzero(cpm.page_state_vector())) == set(owned)
+
+    def test_grant_is_one_program_for_every_k_and_range(self):
+        """50 grants of varying ``k``, ``lo`` and ``hi`` (single and
+        batched) on one allocator lower the grant program at most once:
+        a compile per ``k`` would land inside a serving window."""
+        from repro.cpm.pool import allocator
+        a = SlotAllocator(2, n_pages=56)
+        s0, s1 = a.alloc(), a.alloc()
+        rng = np.random.default_rng(0)
+        before = allocator._page_grant._cache_size()
+        after_first = None
+        for i in range(50):
+            lo = int(rng.integers(0, 28))
+            hi = int(rng.integers(lo + 1, 57))
+            k = int(rng.integers(1, 6))
+            if i % 3:
+                a.alloc_pages((s0, s1)[i % 2], k, lo, hi)
+            else:
+                a.grant_pages([(s0, k), (s1, 1 + i % 4)], lo, hi)
+            if i % 8 == 7:                             # pages back
+                a.free(s1)
+                assert a.alloc() == s1
+            if after_first is None:
+                after_first = allocator._page_grant._cache_size()
+        assert after_first - before <= 1
+        assert allocator._page_grant._cache_size() == after_first
+        assert a.pages(s0) and a.page_free_count() < 56
+
+
 # ---------------------------------------------------------------------------
 # banks: paged row movement, reference vs pallas kernels
 # ---------------------------------------------------------------------------

@@ -404,3 +404,116 @@ class TestPagedLayout:
             [b.data for b in pool.banks], [b.lens for b in pool.banks],
             jnp.asarray(pt), pool.tok_lens, jax.random.PRNGKey(7))
         assert n == 3 * pool.n_banks
+
+
+# ---------------------------------------------------------------------------
+# the between-chunk page top-up: one grant per bank, ordered under pressure
+# ---------------------------------------------------------------------------
+
+def _one_at_a_time_topup(pool):
+    """What the top-up must do, replayed on an ``OracleAllocator`` that
+    holds the pool's page lists: each session's shortfall granted alone,
+    youngest first, a session that cannot be covered parked (its pages
+    freed) on the spot.  Returns ``({sid: page list}, [parked sids])``."""
+    from repro.cpm.pool import OracleAllocator
+    orc = OracleAllocator(pool.slots, n_pages=pool.total_pages)
+    active = pool.table.active()
+    for sess in active:
+        orc.used[sess.slot] = 0
+        orc.page_lists[sess.slot] = pool.alloc.pages(sess.slot)
+        orc.page_owner.update({p: sess.slot
+                               for p in orc.page_lists[sess.slot]})
+    parked = []
+    for sess in sorted(active, key=lambda s: (s.first_admit_step, s.sid),
+                       reverse=True):
+        need = min(pool.C, pool.pages_for(
+            sess.prompt_len + sess.emitted + pool.chunk))
+        have = len(orc.pages(sess.slot))
+        lo, hi = pool._page_range(pool._bank_of(sess.slot))
+        if need > have and orc.alloc_pages(sess.slot, need - have,
+                                           lo, hi) is None:
+            parked.append(sess.sid)
+            orc.free(sess.slot)
+    return ({s.sid: orc.pages(s.slot) for s in active
+             if s.sid not in parked}, parked)
+
+
+def _grants_inside(span):
+    from repro.obs import TRACER
+    return [g for g in TRACER.spans("alloc.grant")
+            if span.ts <= g.ts <= span.ts + span.dur]
+
+
+class TestPageTopUp:
+    def test_steady_decoding_is_one_grant_per_bank(self, granite):
+        """Pages of 2 tokens and chunks of 2: every live session crosses
+        a page each chunk, and each bank's shortfalls go out as one
+        batched grant per tick; outputs stay identical to solo runs."""
+        from repro.obs import TRACER
+        lens = [6, 9, 7, 8]
+        budgets = [12, 10, 14, 11]
+        prompts = [_prompt(300 + i, s, CFG) for i, s in enumerate(lens)]
+        want = [_solo(granite, p, b) for p, b in zip(prompts, budgets)]
+        pool = granite.session_pool(slots=4, n_banks=2, chunk=2,
+                                    page_size=2)
+        sids = [pool.submit(p, b) for p, b in zip(prompts, budgets)]
+        TRACER.clear()
+        outs = pool.drain()
+        for sid, w in zip(sids, want):
+            np.testing.assert_array_equal(outs[sid], w)
+        tops = TRACER.spans("pool.ensure_pages")
+        assert len(tops) >= 4
+        full = 0
+        for sp in tops:
+            grants = _grants_inside(sp)
+            assert len(grants) <= pool.n_banks
+            assert {g.args["path"] for g in grants} <= {"batched"}
+            assert sum(g.args["requests"] for g in grants) \
+                == sp.args["sessions"]
+            assert sum(g.args["pages"] for g in grants) == sp.args["pages"]
+            full += len(grants) == pool.n_banks and sp.args["sessions"] == 4
+        assert full >= 3                    # both banks, all four sessions
+        TRACER.clear()
+
+    def test_page_pressure_takes_the_ordered_path(self, granite,
+                                                  monkeypatch):
+        """Under page pressure a bank that cannot cover its whole
+        shortfall runs the ordered loop: the youngest parks first, and
+        every tick leaves the page lists and parks the one-at-a-time
+        replay on the oracle gives; outputs stay identical to solo runs."""
+        from repro.obs import TRACER
+        lens = [8, 12, 10, 9, 11, 8]
+        budgets = [9, 12, 6, 8, 10, 7]
+        prompts = [_prompt(310 + i, s, CFG) for i, s in enumerate(lens)]
+        want = [_solo(granite, p, b) for p, b in zip(prompts, budgets)]
+        pool = granite.session_pool(slots=4, n_banks=2, chunk=2,
+                                    page_size=4, pages_per_bank=8)
+        real_topup, real_park = pool._ensure_pages, pool.park
+        parked: list[int] = []
+        seen = {"ordered": 0, "batched": 0}
+
+        def park(sid):
+            parked.append(sid)
+            real_park(sid)
+
+        def checked_topup():
+            lists, parks = _one_at_a_time_topup(pool)
+            del parked[:]
+            TRACER.clear()
+            real_topup()
+            assert parked == parks
+            assert {s.sid: pool.alloc.pages(s.slot)
+                    for s in pool.table.active()} == lists
+            for g in TRACER.spans("alloc.grant"):
+                seen[g.args["path"]] += 1
+
+        monkeypatch.setattr(pool, "park", park)
+        monkeypatch.setattr(pool, "_ensure_pages", checked_topup)
+        sids = [pool.submit(p, b) for p, b in zip(prompts, budgets)]
+        outs = pool.drain()
+        for sid, w in zip(sids, want):
+            np.testing.assert_array_equal(outs[sid], w)
+        assert pool.stats()["page_stalls"] > 0        # pressure hit
+        assert seen["ordered"] > 0 and seen["batched"] > 0
+        assert pool.alloc.page_free_count() == pool.total_pages
+        TRACER.clear()

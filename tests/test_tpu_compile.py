@@ -201,3 +201,19 @@ def test_allocator_page_file_ops(one_chip, n_pages):
         return dev.compare(0), dev.global_limit("min")
     hlo = _compiled_hlo(queries, _spec(one_chip, (n_pages,)))
     assert hlo.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("n_pages", [1024, 2048])
+def test_allocator_page_grant_program(one_chip, n_pages):
+    """The allocator's compiled page grant (compare, drain and claim in
+    one program, ``lo``, ``hi`` and ``k`` traced) with the Pallas compare
+    a TPU's ``backend="auto"`` picks for a page file this long."""
+    from repro.cpm.pool.allocator import _page_grant
+
+    def grant(state, lo, hi, k):
+        return _page_grant(state, lo, hi, k, n_used=n_pages,
+                           backend="pallas", interpret=False)
+    scalar = _spec(one_chip, ())
+    hlo = _compiled_hlo(grant, _spec(one_chip, (n_pages,)), scalar, scalar,
+                        scalar)
+    assert "tpu_custom_call" in hlo
