@@ -61,10 +61,10 @@ def main(argv=None) -> int:
             if engine.params is None:
                 engine.params = harness.make_params(cfg, seed)
             t = time.perf_counter()
-            reqs = schedule(traffic, seed, args.seconds, cfg["vocab_size"],
+            reqs = schedule(traffic, seed, args.seconds, harness.vocab(cfg),
                             extra=0.25)
             win = await harness.serve_window(gw, reqs, args.seconds, clock)
-            checks = harness.served_checks(win, cfg["vocab_size"])
+            checks = harness.served_checks(win, harness.vocab(cfg))
             engine.params = None            # the reference needs the room
             gc.collect()
             sample = harness.sample_for_reference(win, seed)

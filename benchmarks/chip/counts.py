@@ -1,54 +1,12 @@
-"""Operations and bytes the served work needs, computed from shapes.
+"""Operations and bytes of the program's kernels, computed from the shapes
+of a call, and the least time they take at a chip's peaks.
 
-Useful FLOPs count real work only: live rows, the routed top-k experts
-(not the capacity padding the program computes), causal attention at
-each row's real context, and logits only where the program computes
-them (the last prompt position in prefill, every decode step).  A
-multiply-add is two FLOPs.
+The model's useful FLOPs are its family's (``families/``), which a metric
+reader sees as ``run.counts``.  A new kernel brings its cost function in
+its own metric reader.
 """
 
 from __future__ import annotations
-
-from weights import dims
-
-
-def layer_matmul_flops(cfg: dict) -> float:
-    """Weight-matrix FLOPs of one token through one layer."""
-    m = dims(cfg)
-    d, h, kvh, dh, f = m["d"], m["h"], m["kvh"], m["dh"], m["f"]
-    attn = 2 * d * (h + 2 * kvh) * dh + 2 * h * dh * d
-    if m["E"]:
-        ffn = 2 * d * m["E"] + m["k"] * 2 * 3 * d * f
-    else:
-        ffn = 2 * 3 * d * f
-    return float(attn + ffn)
-
-
-def attention_flops(cfg: dict, keys: float) -> float:
-    """Scores and weighted values of one query against ``keys`` keys, over
-    all layers and heads."""
-    m = dims(cfg)
-    return 4.0 * m["L"] * m["h"] * m["dh"] * keys
-
-
-def logits_flops(cfg: dict) -> float:
-    m = dims(cfg)
-    return 2.0 * m["d"] * m["V"]
-
-
-def prefill_flops(cfg: dict, s: int) -> float:
-    """One prompt of ``s`` tokens: every position through every layer,
-    causal attention (position i sees i + 1 keys), logits at the last."""
-    m = dims(cfg)
-    return (s * m["L"] * layer_matmul_flops(cfg)
-            + attention_flops(cfg, s * (s + 1) / 2) + logits_flops(cfg))
-
-
-def decode_flops(cfg: dict, keys: int) -> float:
-    """One decode token that attends to ``keys`` keys (its own included)."""
-    m = dims(cfg)
-    return (m["L"] * layer_matmul_flops(cfg) + attention_flops(cfg, keys)
-            + logits_flops(cfg))
 
 
 def flash_attention_cost(b: int, h: int, kvh: int, s: int, dh: int,

@@ -24,6 +24,8 @@ import time
 
 import numpy as np
 
+import families
+
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 CACHE_DIR = ROOT / ".jax_cache"
@@ -52,15 +54,12 @@ def load_cell(name: str):
 
 
 def rehearsal_sizes(cfg: dict, traffic: dict) -> tuple[dict, dict]:
-    """A tiny configuration and traffic of the same shape, for the CPU."""
-    cfg = dict(cfg, num_hidden_layers=2, hidden_size=64, head_dim=16,
-               num_attention_heads=4, num_key_value_heads=2,
-               intermediate_size=96, vocab_size=256,
+    """A tiny configuration and traffic of the same shape, for the CPU:
+    the model at its family's ``tiny`` size, a small pool, short prompts
+    and answers."""
+    cfg = dict(families.of(cfg).tiny(cfg),
                pool=dict(cfg["pool"], slots=4, max_len=64, page_size=8,
                          chunk=4))
-    if cfg.get("num_local_experts"):
-        cfg.update(num_local_experts=4, num_experts_per_tok=2,
-                   capacity_factor=2.0)
     classes = [8, 16, 24, 32][:len(traffic["prompt"]["classes"])]
     traffic = dict(traffic,
                    arrival=dict(traffic["arrival"], rate_rps=4.0),
@@ -130,22 +129,14 @@ class CompileClock:
 
 # -- the system under test ------------------------------------------------------
 def model_config(cfg: dict):
-    from repro.configs.base import ModelConfig, MoEConfig
-    moe = None
-    if cfg.get("num_local_experts"):
-        moe = MoEConfig(n_experts=cfg["num_local_experts"],
-                        top_k=cfg["num_experts_per_tok"],
-                        capacity_factor=cfg["capacity_factor"])
-    return ModelConfig(
-        name=cfg["name"], family="moe" if moe else "dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg.get("head_dim", 0), moe=moe,
-        ffn="moe" if moe else "swiglu", norm_eps=cfg["rms_norm_eps"],
-        rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg["tie_word_embeddings"])
+    """The program's ``ModelConfig``, from the configuration's family."""
+    return families.of(cfg).model_config(cfg)
+
+
+def vocab(cfg: dict) -> int:
+    """Token ids run from 0 to this: the traffic draws its prompts and the
+    checks bound the served ids by it."""
+    return model_config(cfg).vocab_size
 
 
 def make_params(cfg: dict, seed: int):
@@ -154,7 +145,7 @@ def make_params(cfg: dict, seed: int):
     from repro.models import lm
     import weights
     vp = lm.padded_vocab(model_config(cfg))
-    make = jax.jit(lambda k: weights.program_params(
+    make = jax.jit(lambda k: families.of(cfg).program_params(
         cfg, weights.canonical(cfg, k), vp))
     return jax.block_until_ready(make(weights.key(seed)))
 
@@ -177,9 +168,9 @@ def warm_up(gw, cfg: dict, traffic: dict, seed: int) -> None:
     import jax
     from traffic import warm_shapes
     pool, rng = gw.pool, np.random.default_rng(seed ^ 0x5EED)
-    vocab = cfg["vocab_size"]
+    ids = vocab(cfg)
     for s, k in warm_shapes(traffic):
-        rids = [gw.submit(rng.integers(0, vocab, s, dtype=np.int32), 1)
+        rids = [gw.submit(rng.integers(0, ids, s, dtype=np.int32), 1)
                 for _ in range(k)]
         while not all(gw.request(r).done for r in rids):
             gw.tick()
@@ -209,7 +200,7 @@ async def warm_stream(gw, cfg: dict, traffic: dict, seed: int) -> None:
     lens = traffic["warmup"]["prompt_lens"]
     await gw.start()
     rids = [await gw.asubmit(
-        rng.integers(0, cfg["vocab_size"], lens[i % len(lens)],
+        rng.integers(0, vocab(cfg), lens[i % len(lens)],
                      dtype=np.int32), 2 * pool.chunk + 1)
         for i in range(pool.slots)]
     for r in rids:

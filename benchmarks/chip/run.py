@@ -5,7 +5,9 @@
         --seconds <s> --trace <0|1> [--rehearse]
 
 The cell is a ``workloads`` entry of ``BENCHMARK.json``: a configuration
-file (``configs/``) under a traffic file (``traffic/``).  A run goes
+file (``configs/``) under a traffic file (``traffic/``).  The file's
+``family`` (``families/``) maps it onto the program and counts its FLOPs;
+its ``reference`` (``reference/``) is the float32 reference.  A run goes
 through these phases in order:
 
 1. device check: JAX's platform must be ``tpu`` with the chips the cell
@@ -51,6 +53,7 @@ import types        # noqa: E402
 HERE = pathlib.Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
+import families     # noqa: E402
 import harness      # noqa: E402
 
 
@@ -111,7 +114,7 @@ def main(argv=None) -> int:
     harness.warm_up(gw, cfg, traffic, args.seed)
 
     from traffic import schedule
-    reqs = schedule(traffic, args.seed, args.seconds, cfg["vocab_size"])
+    reqs = schedule(traffic, args.seed, args.seconds, harness.vocab(cfg))
     trace_dir = None
     if args.trace:
         trace_dir = str(harness.ROOT / ".bench_traces"
@@ -128,7 +131,7 @@ def main(argv=None) -> int:
 
     setup_s, win = asyncio.run(served())
     peak = harness.device_peak_bytes()
-    checks = harness.served_checks(win, cfg["vocab_size"])
+    checks = harness.served_checks(win, harness.vocab(cfg))
     del gw
     harness.free()
 
@@ -171,12 +174,13 @@ def main(argv=None) -> int:
     }
     correct = all(c["value"] <= c["limit"] for c in compared.values())
 
-    import counts
-    # what a metric reader (``metrics/<name>.py``) sees of this run
+    # what a metric reader (``metrics/<name>.py``) sees of this run;
+    # ``counts`` is the family, whose ``prefill_flops`` and
+    # ``decode_flops`` count the model's useful work
     run = types.SimpleNamespace(
         cell=cell, cfg=cfg, device=device, traffic=traffic,
         seconds=args.seconds, setup_s=setup_s, win=win, trace=summary,
-        counts=counts)
+        counts=families.of(cfg))
     metrics = {}
     if not args.rehearse:
         metrics = read_metrics(wanted_metrics(bench, cell, args.trace), run)

@@ -84,7 +84,7 @@ def main(argv=None) -> int:
             at = dict(traffic, arrival=dict(traffic["arrival"],
                                             rate_rps=rate))
             reqs = schedule(at, args.seed + i, args.seconds,
-                            cfg["vocab_size"], extra=0.25)
+                            harness.vocab(cfg), extra=0.25)
             t = time.perf_counter()
             win = await harness.serve_window(gw, reqs, args.seconds, clock)
             row = {"rate_rps": rate, **judge(win, limits),
